@@ -193,13 +193,7 @@ def print_lut_candidates(task: str = "nid", device: str = "cpu",
     from repro.configs import paper_tasks
     from repro.kernels import autotune
 
-    cfg = paper_tasks.task_config(task)
-    layers, off = [], 0
-    for l, spec in enumerate(cfg.layers):
-        layers.append((cfg.prev_width(l), spec.units,
-                       2 ** (cfg.in_bits(l) * spec.fan_in), off,
-                       spec.fan_in, cfg.in_bits(l), int(spec.assemble)))
-        off += spec.units
+    layers = autotune.config_layers(paper_tasks.task_config(task))
     rows = autotune.roofline_candidates(layers, batch=batch, device=device)
     pick = autotune.pick_tuning(layers, batch=batch, device=device)
     hdr = (f"{'mode':<9} {'block_b':>7} {'tile':>5} {'comp us':>8} "
@@ -236,7 +230,8 @@ def write_lut_choices(out: str = LUT_CHOICES_OUT) -> str:
     return out
 
 
-def lut_main(out: str, tasks=None, devices=("cpu", "tpu")) -> None:
+def lut_main(out: str, tasks=None,
+             devices=("cpu", "TPU v5 lite")) -> None:
     from repro.configs import paper_tasks
 
     for task in tasks or sorted(paper_tasks.TASKS):

@@ -201,6 +201,9 @@ def main() -> None:
                              "chaos"])
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_persistent_cache
+    use_persistent_cache()
+
     rows: list = []
     outputs = {}
     if args.only in (None, "kernels"):

@@ -30,6 +30,7 @@ f32).  The paper's configs max out at 12; planning raises otherwise.
 from __future__ import annotations
 
 import copy
+import dataclasses
 from typing import Any, Dict, List, Optional
 
 import jax.numpy as jnp
@@ -88,7 +89,7 @@ class FusedCascadeBackend(LookupBackend):
 
         Validates the f32-exactness bound, packs ``amat``/``tables``/
         ``map_<l>``, and stamps the roofline-model tuning for the current
-        device (``autotune.default_tuning``) into ``meta["tuning"]``.
+        device kind (``autotune.default_tuning``) into ``meta["tuning"]``.
         """
         require_mappings(net, "fused.plan")
         cfg = net.cfg
@@ -127,9 +128,8 @@ class FusedCascadeBackend(LookupBackend):
             table = np.asarray(net.tables[l])
             tables[off:off + units, :table.shape[1]] = table
 
-        tuning = autotune.default_tuning(
-            layers, table_itemsize=tables.dtype.itemsize,
-            table_dtype=tables.dtype.name)
+        tuning = autotune.default_tuning(layers,
+                                         table_dtype=tables.dtype.name)
         meta: Dict[str, Any] = {
             "schema": PLAN_SCHEMA,
             "layers": layers,
@@ -171,9 +171,8 @@ class FusedCascadeBackend(LookupBackend):
         for l, spec in enumerate(cfg.layers):
             if not spec.assemble:
                 buffers[f"map_{l}"] = np.asarray(net.mappings[l], np.int32)
-        tuning = autotune.default_tuning(
-            layers, table_itemsize=tables.dtype.itemsize,
-            table_dtype=tables.dtype.name)
+        tuning = autotune.default_tuning(layers,
+                                         table_dtype=tables.dtype.name)
         meta = dict(plan.meta)
         meta.update(schema=PLAN_SCHEMA, layers=layers,
                     input_span=2 ** cfg.in_bits(0),
@@ -195,11 +194,13 @@ class FusedCascadeBackend(LookupBackend):
         import jax
 
         layers = [tuple(map(int, lm)) for lm in plan.meta["layers"]]
-        itemsize = np.dtype(plan.meta["table_dtype"]).itemsize
         if candidates is None:
             candidates = autotune.measurement_grid(
-                layers, table_itemsize=itemsize,
-                table_dtype=plan.meta["table_dtype"])
+                layers, table_dtype=plan.meta["table_dtype"])
+        # what is timed here is this device's choice (see device_tuning)
+        kind = autotune.device_kind()
+        candidates = [dataclasses.replace(c, device_kind=kind)
+                      for c in candidates]
         span = int(plan.meta.get("input_span", 2))
         codes = jnp.asarray(np.random.RandomState(seed).randint(
             0, span, size=(rows, layers[0][0])), jnp.int32)
@@ -217,10 +218,25 @@ class FusedCascadeBackend(LookupBackend):
                         tuning_report=report)
         return out
 
+    def device_tuning(self, plan: ExecutionPlan) -> autotune.KernelTuning:
+        """The tuning ``plan`` runs with on this process's device.
+
+        A tuning picked for another ``device_kind`` (a plan made on a CPU
+        host and served from a TPU) is re-picked for this device, and the
+        new choice is written back into ``plan.meta`` so a later ``save``
+        records what ran."""
+        tuning = autotune.KernelTuning.from_meta(plan.meta.get("tuning"))
+        fresh = autotune.for_device(tuning, plan.meta["layers"],
+                                    table_dtype=plan.meta.get("table_dtype"))
+        if fresh is not tuning:
+            plan.meta["tuning"] = fresh.to_meta()
+        return fresh
+
     def run(self, plan: ExecutionPlan, codes: Any):
-        """Execute the cascade with the plan's persisted tuning (the
+        """Execute the cascade with the plan's tuning for this device (the
         ``ops.lut_cascade`` dispatcher picks Pallas vs XLA from it)."""
         from repro.kernels import ops
+        tuning = self.device_tuning(plan)
         layers = tuple(tuple(int(v) for v in l) for l in plan.meta["layers"])
         # the XLA path needs a mapping for every non-assemble layer; fall
         # back to Pallas-only dispatch when any is missing (foreign plan)
@@ -233,7 +249,6 @@ class FusedCascadeBackend(LookupBackend):
                 if f"map_{l}" in plan.buffers else None
                 for l in range(len(layers)))
         return ops.lut_cascade(jnp.asarray(codes, jnp.int32),
-                               jnp.asarray(plan.buffers["amat"]),
-                               jnp.asarray(plan.buffers["tables"]),
+                               plan.buffers["amat"], plan.buffers["tables"],
                                layers=layers, mappings=mappings,
-                               tuning=plan.meta.get("tuning"))
+                               tuning=tuning)

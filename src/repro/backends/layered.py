@@ -90,7 +90,7 @@ class LayeredBackend(LookupBackend):
         """
         from jax.sharding import PartitionSpec as P
 
-        from repro.backends.placement import shard_map, unit_shard_buffers
+        from repro.backends.placement import unit_shard_buffers
         from repro.core import quant
         from repro.kernels import ops
 
@@ -118,10 +118,10 @@ class LayeredBackend(LookupBackend):
                     out, ax, axis=1, tiled=True)[:, :units]
             return codes  # pragma: no cover - loop always returns
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(),) + (P(ax, None),) * len(bufs),
-            out_specs=P(None, ax))
+            out_specs=P(None, ax), check_vma=False)
         n_out = meta[-1][0]
         consts = tuple(jnp.asarray(b) for b in bufs)
 

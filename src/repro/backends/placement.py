@@ -37,28 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map_impl  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
 from repro.dist.sharding import dp_axes
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off.
-
-    The checker has no rule for ``pallas_call`` (and the kwarg disabling
-    it was renamed ``check_rep`` -> ``check_vma`` across jax versions), so
-    resolve the name once here; correctness is covered by the bit-identity
-    tests, not the static checker.
-    """
-    try:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - newer jax renamed the kwarg
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.backends.base import ExecutionPlan, LookupBackend
@@ -159,8 +138,10 @@ def _batch_sharded(backend: "LookupBackend", plan: "ExecutionPlan",
     mesh, axes = placement.mesh, placement.resolved_axes()
     n = placement.num_shards()
     spec = P(axes)
-    local = shard_map(lambda c: backend.run(plan, c), mesh=mesh,
-                      in_specs=spec, out_specs=spec)
+    # check_vma=False: the replication checker has no rule for
+    # pallas_call; bit-identity tests cover correctness instead
+    local = jax.shard_map(lambda c: backend.run(plan, c), mesh=mesh,
+                          in_specs=spec, out_specs=spec, check_vma=False)
 
     def run(codes):
         b = codes.shape[0]

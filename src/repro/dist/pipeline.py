@@ -15,7 +15,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -55,7 +54,7 @@ def make_pipelined_fn(layer_fn: Callable[[Array, Array], Array], mesh: Mesh,
             axis_name)
         return out
 
-    fn = shard_map(stage_body, mesh=mesh,
-                   in_specs=(P(axis_name), P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(stage_body, mesh=mesh,
+                       in_specs=(P(axis_name), P()), out_specs=P(),
+                       check_vma=False)
     return jax.jit(fn)

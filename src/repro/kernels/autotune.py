@@ -24,9 +24,11 @@ that decision:
     task as an artifact.
 
 The model is deliberately small: lookup tables admit no data reuse beyond
-what fits in VMEM, so the only real questions are "do the tables fit?"
-(picks resident vs streamed) and "how big a batch tile keeps the one-hot
-intermediate inside the VMEM budget?" (picks ``block_b``).
+what fits in VMEM, so the only real questions are "do the packed buffers
+fit?" (picks resident vs streamed) and "how big a batch tile keeps the
+kernel inside the VMEM budget?" (picks ``block_b``).  Both are answered
+against :data:`DEVICE_MODELS`, keyed by JAX's ``device_kind``, with the
+VMEM the compiled kernel allocates (``lut_cascade.vmem_bytes``).
 """
 from __future__ import annotations
 
@@ -35,20 +37,38 @@ import math
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from repro.kernels.lut_gather import VMEM_TILE_BUDGET
-
-# peak flops / HBM-equivalent bandwidth per device family.  TPU numbers
-# match benchmarks/roofline.py (v5p-class); the CPU row models the host
-# streaming from LLC/DRAM — coarse on purpose, the model only has to rank
-# candidates, not predict wall-clock.
-DEVICE_MODELS: Dict[str, Dict[str, float]] = {
-    "tpu": {"peak_flops": 197e12, "hbm_bw": 819e9, "vmem_bytes": 64 * 2**20},
-    "gpu": {"peak_flops": 60e12, "hbm_bw": 1.5e12, "vmem_bytes": 48 * 2**20},
-    "cpu": {"peak_flops": 2e11, "hbm_bw": 4e10, "vmem_bytes": 8 * 2**20},
+# Peak rates and the kernel VMEM budget per device, keyed by JAX's
+# ``device_kind``.  A device missing here is an error (:func:`device_kind`):
+# no peak or VMEM size is ever assumed.
+DEVICE_MODELS: Dict[str, Dict[str, Any]] = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,        # bf16
+        "peak_int8_ops": 393e12,
+        "hbm_bw": 819e9,
+        "hbm_bytes": 16e9,
+        # the scoped-VMEM limit the v5e compiler enforces per kernel
+        # ("Scoped allocation with size ... and limit 16.00M"); the chip
+        # has more VMEM, but a kernel gets this much unless it asks
+        "vmem_bytes": 16 * 2**20,
+        "source": "Google Cloud documentation, \"TPU v5e\" (peaks, HBM); "
+                  "vmem_bytes: Mosaic's scoped-VMEM limit on v5e",
+    },
+    "cpu": {
+        # the host streaming from LLC/DRAM; coarse on purpose: the model
+        # only ranks candidates there, and the CPU serves the XLA path
+        "peak_flops": 2e11,
+        "hbm_bw": 4e10,
+        "vmem_bytes": 8 * 2**20,
+        "source": "rough host model, ranking only (no measurement)",
+    },
 }
 
 BLOCK_B_CANDIDATES = (64, 128, 256, 512, 1024)
-UNIT_TILE_CANDIDATES = (8, 16, 32)
+# One lane tile per streamed phase: compiled for a v5e, every wider tile
+# needs more VMEM than the kernel model counts (at mnist width and
+# block_b 256: 20.6 MiB at 256 lanes against 8.8 MiB at 128), so the
+# model offers none.
+UNIT_TILE_CANDIDATES = (128,)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,16 +78,19 @@ class KernelTuning:
     ``impl`` ``None`` means auto: compiled Pallas on TPU, the jnp
     flat-gather path wherever Pallas would run interpreted (``ops``
     resolves it per process, so one artifact serves both device kinds).
-    ``source`` records provenance: ``default`` (schema migration),
-    ``roofline`` (modeled) or ``measured`` (timed on this host).
+    ``source`` records provenance: ``default`` (planning), ``roofline``
+    (modeled) or ``measured`` (timed on this host).  ``device_kind`` is
+    the device the choice was made for; a plan run on another kind is
+    re-picked (:func:`for_device`).
     """
 
     impl: Optional[str] = None          # None=auto | "xla" | "pallas"
     mode: str = "resident"              # "resident" | "streamed"
     block_b: int = 256
-    unit_tile: int = 8
+    unit_tile: int = 128
     table_dtype: Optional[str] = None   # narrowest that fits when None
     source: str = "default"
+    device_kind: Optional[str] = None
 
     def to_meta(self) -> Dict[str, Any]:
         """JSON-serializable form for ``ExecutionPlan.meta['tuning']``."""
@@ -83,48 +106,49 @@ class KernelTuning:
         return cls(**{k: v for k, v in meta.items() if k in fields})
 
 
-def device_kind(name: Optional[str] = None) -> str:
-    """Normalize a jax backend name to a DEVICE_MODELS key (default: the
-    current process backend)."""
-    if name is None:
+def device_kind(kind: Optional[str] = None) -> str:
+    """Validate a ``device_kind`` against :data:`DEVICE_MODELS` (default:
+    this process's first device).  Raises for a device with no model."""
+    if kind is None:
         import jax
-        name = jax.default_backend()
-    return name if name in DEVICE_MODELS else "cpu"
+        kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_MODELS:
+        raise ValueError(
+            f"no device model for device_kind {kind!r}; known: "
+            f"{sorted(DEVICE_MODELS)} (add a row with its published peaks "
+            "and its compiler's VMEM limit to autotune.DEVICE_MODELS)")
+    return kind
+
+
+def config_layers(cfg) -> List[Tuple[int, ...]]:
+    """The fused plan's v2 layer tuples of an ``AssembleConfig`` (shapes
+    only: what the model needs, without folding any tables)."""
+    layers, off = [], 0
+    for l, spec in enumerate(cfg.layers):
+        layers.append((cfg.prev_width(l), spec.units,
+                       2 ** (cfg.in_bits(l) * spec.fan_in), off,
+                       spec.fan_in, cfg.in_bits(l), int(spec.assemble)))
+        off += spec.units
+    return layers
 
 
 # ---------------------------------------------------------------------------
 # the roofline model
 # ---------------------------------------------------------------------------
 
-def _layer_dims(layers: Sequence[Sequence[int]]):
-    l4 = [(int(p), int(u), int(e), int(o)) for p, u, e, o, *_ in layers]
-    total_units = sum(u for _, u, _, _ in l4)
-    max_prev = max(p for p, _, _, _ in l4)
-    max_entries = max(e for _, _, e, _ in l4)
-    return l4, total_units, max_prev, max_entries
-
-
-def resident_bytes(layers: Sequence[Sequence[int]],
-                   table_itemsize: int) -> int:
-    """VMEM bytes the resident kernel must hold for the whole cascade
-    (packed tables + address matrices)."""
-    _, total_units, max_prev, max_entries = _layer_dims(layers)
-    return (total_units * max_entries * table_itemsize
-            + max_prev * total_units * 4)
-
-
 def roofline_candidates(layers: Sequence[Sequence[int]], *,
-                        table_itemsize: int = 4, batch: int = 4096,
+                        batch: int = 4096,
                         device: Optional[str] = None) -> List[Dict[str, Any]]:
     """The modeled candidate grid: one row per (mode, block_b[, unit_tile])
-    with compute time, memory time, the binding roof, and VMEM feasibility.
+    with compute time, memory time, the binding roof, and VMEM feasibility
+    under the device's kernel VMEM budget.
 
     Rows are plain dicts so ``benchmarks/roofline.py`` can print them and
     the nightly choice-table artifact can serialize them verbatim.
     """
     # local import keeps this module importable without pulling kernels in
-    from repro.kernels.lut_cascade import (_phase_layout, cascade_bytes,
-                                           cascade_flops, layers_v1)
+    from repro.kernels.lut_cascade import (cascade_bytes, cascade_flops,
+                                           layers_v1, vmem_bytes)
     dev = device_kind(device)
     m = DEVICE_MODELS[dev]
     l4 = layers_v1(layers)
@@ -133,24 +157,16 @@ def roofline_candidates(layers: Sequence[Sequence[int]], *,
     for mode in ("resident", "streamed"):
         for block_b in BLOCK_B_CANDIDATES:
             for unit_tile in (UNIT_TILE_CANDIDATES if mode == "streamed"
-                              else (0,)):
-                if mode == "resident":
-                    worst = max(u * e for _, u, e, _ in l4)
-                    vmem = (resident_bytes(l4, table_itemsize)
-                            + block_b * worst * 4)
-                else:
-                    _, _, _, _, _, a_dim = _phase_layout(l4, unit_tile)
-                    max_e = max(e for _, _, e, _ in l4)
-                    vmem = (block_b * (unit_tile * max_e + 2 * a_dim) * 4
-                            + 2 * unit_tile * (max_e * table_itemsize
-                                               + a_dim * 4))
-                byts = cascade_bytes(l4, batch, table_itemsize, mode=mode,
-                                     block_b=block_b)
+                              else (None,)):
+                tile = {} if unit_tile is None else {"unit_tile": unit_tile}
+                vmem = vmem_bytes(l4, mode=mode, block_b=block_b, **tile)
+                byts = cascade_bytes(l4, batch, mode=mode, block_b=block_b,
+                                     **tile)
                 t_comp = flops / m["peak_flops"]
                 t_mem = byts / m["hbm_bw"]
                 rows.append({
                     "device": dev, "mode": mode, "block_b": block_b,
-                    "unit_tile": unit_tile or None,
+                    "unit_tile": unit_tile,
                     "flops": flops, "bytes": byts,
                     "t_compute_us": round(t_comp * 1e6, 3),
                     "t_memory_us": round(t_mem * 1e6, 3),
@@ -163,8 +179,13 @@ def roofline_candidates(layers: Sequence[Sequence[int]], *,
     return rows
 
 
-def pick_tuning(layers: Sequence[Sequence[int]], *,
-                table_itemsize: int = 4, batch: int = 4096,
+def _tuning(row: Dict[str, Any], **kw) -> KernelTuning:
+    return KernelTuning(mode=row["mode"], block_b=row["block_b"],
+                        unit_tile=row["unit_tile"] or UNIT_TILE_CANDIDATES[0],
+                        device_kind=row["device"], **kw)
+
+
+def pick_tuning(layers: Sequence[Sequence[int]], *, batch: int = 4096,
                 device: Optional[str] = None,
                 table_dtype: Optional[str] = None) -> KernelTuning:
     """Model-driven choice: the fastest VMEM-feasible roofline candidate.
@@ -172,34 +193,40 @@ def pick_tuning(layers: Sequence[Sequence[int]], *,
     Ties break toward resident mode (no re-streaming) and larger batch
     tiles (fewer grid steps).  ``impl`` stays ``None`` (auto) so the same
     persisted plan runs compiled Pallas on TPU and the jnp flat-gather
-    path on interpret-mode hosts.
+    path on interpret-mode hosts.  When nothing fits, the smallest
+    streamed tiling is returned, and compiling it fails loudly.
     """
-    rows = [r for r in roofline_candidates(
-        layers, table_itemsize=table_itemsize, batch=batch, device=device)
-        if r["fits_vmem"]]
-    if not rows:  # nothing fits the model's VMEM budget: stream, smallest
-        return KernelTuning(mode="streamed", block_b=BLOCK_B_CANDIDATES[0],
-                            unit_tile=UNIT_TILE_CANDIDATES[0],
-                            table_dtype=table_dtype, source="roofline")
-    rows.sort(key=lambda r: (r["t_us"],
+    rows = roofline_candidates(layers, batch=batch, device=device)
+    fits = [r for r in rows if r["fits_vmem"]]
+    if not fits:
+        return _tuning(min(rows, key=lambda r: r["vmem_bytes"]),
+                       table_dtype=table_dtype, source="roofline")
+    fits.sort(key=lambda r: (r["t_us"],
                              0 if r["mode"] == "resident" else 1,
                              -r["block_b"]))
-    best = rows[0]
-    return KernelTuning(mode=best["mode"], block_b=best["block_b"],
-                        unit_tile=best["unit_tile"] or 8,
-                        table_dtype=table_dtype, source="roofline")
+    return _tuning(fits[0], table_dtype=table_dtype, source="roofline")
 
 
 def default_tuning(layers: Sequence[Sequence[int]], *,
-                   table_itemsize: int = 4,
                    table_dtype: Optional[str] = None) -> KernelTuning:
-    """The tuning stamped on plans that never ran the tuner (fresh plans
-    before planning-time tuning, v1 plans migrated across the schema
-    bump): the roofline pick for the current device, ``source="default"``
-    so tooling can tell it apart from an explicit tuner run."""
-    t = pick_tuning(layers, table_itemsize=table_itemsize,
-                    table_dtype=table_dtype)
+    """The tuning stamped on plans that never ran the tuner (fresh plans,
+    v1 plans migrated across the schema bump): the roofline pick for the
+    current device, ``source="default"`` so tooling can tell it apart
+    from an explicit tuner run."""
+    t = pick_tuning(layers, table_dtype=table_dtype)
     return dataclasses.replace(t, source="default")
+
+
+def for_device(tuning: KernelTuning, layers: Sequence[Sequence[int]], *,
+               table_dtype: Optional[str] = None) -> KernelTuning:
+    """``tuning`` when it was picked for this process's device kind, else
+    the roofline pick for this device (a plan tuned on a CPU host and
+    served from a TPU, or the other way round, is re-picked, never run
+    with the other device's tiles)."""
+    kind = device_kind()
+    if tuning.device_kind == kind:
+        return tuning
+    return pick_tuning(layers, device=kind, table_dtype=table_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -238,19 +265,14 @@ def measure_tuning(run_factory: Callable[[KernelTuning], Callable[[], Any]],
 
 
 def measurement_grid(layers: Sequence[Sequence[int]], *,
-                     table_itemsize: int = 4,
                      table_dtype: Optional[str] = None,
                      max_candidates: int = 6) -> List[KernelTuning]:
     """A small measurement grid seeded by the roofline ranking: the model
     orders the VMEM-feasible candidates, measurement confirms the top few
     (model-guided search instead of brute force)."""
-    rows = [r for r in roofline_candidates(layers,
-                                           table_itemsize=table_itemsize)
-            if r["fits_vmem"]]
+    rows = [r for r in roofline_candidates(layers) if r["fits_vmem"]]
     rows.sort(key=lambda r: r["t_us"])
-    grid = [KernelTuning(mode=r["mode"], block_b=r["block_b"],
-                         unit_tile=r["unit_tile"] or 8,
-                         table_dtype=table_dtype, source="roofline")
+    grid = [_tuning(r, table_dtype=table_dtype, source="roofline")
             for r in rows[:max_candidates]]
     return grid or [KernelTuning(table_dtype=table_dtype)]
 
@@ -260,7 +282,7 @@ def measurement_grid(layers: Sequence[Sequence[int]], *,
 # ---------------------------------------------------------------------------
 
 def choice_table(tasks: Optional[Sequence[str]] = None,
-                 devices: Sequence[str] = ("cpu", "tpu"),
+                 devices: Sequence[str] = ("cpu", "TPU v5 lite"),
                  batch: int = 4096) -> Dict[str, Any]:
     """Per-(task, device) autotuner choices over the paper configs.
 
@@ -268,26 +290,18 @@ def choice_table(tasks: Optional[Sequence[str]] = None,
     the task configs alone, so this runs in seconds and is uploaded by
     the nightly CI as the autotuner audit artifact."""
     from repro.configs import paper_tasks
+    from repro.kernels.lut_cascade import vmem_bytes
     tasks = list(tasks or sorted(paper_tasks.TASKS))
     out: Dict[str, Any] = {"batch": batch, "choices": []}
     for task in tasks:
-        cfg = paper_tasks.task_config(task)
-        layers, off = [], 0
-        for l, spec in enumerate(cfg.layers):
-            entries = 2 ** (cfg.in_bits(l) * spec.fan_in)
-            layers.append((cfg.prev_width(l), spec.units, entries, off,
-                           spec.fan_in, cfg.in_bits(l),
-                           int(spec.assemble)))
-            off += spec.units
-        max_bits = max(spec.bits for spec in cfg.layers)
-        itemsize = 1 if max_bits <= 7 else (2 if max_bits <= 15 else 4)
+        layers = config_layers(paper_tasks.task_config(task))
         for dev in devices:
-            t = pick_tuning(layers, table_itemsize=itemsize, batch=batch,
-                            device=dev)
+            t = pick_tuning(layers, batch=batch, device=dev)
             out["choices"].append({
                 "task": task, "device": dev,
-                "table_itemsize": itemsize,
-                "resident_bytes": resident_bytes(layers, itemsize),
+                "vmem_bytes": vmem_bytes(layers, mode=t.mode,
+                                         block_b=t.block_b,
+                                         unit_tile=t.unit_tile),
                 "tuning": t.to_meta(),
             })
     return out

@@ -14,20 +14,20 @@ implementations selected by the autotuner (`kernels.autotune`) via
     interpret mode.
   * :func:`lut_cascade_pallas` ``mode="resident"`` — single ``pallas_call``,
     grid over batch only, every layer's table VMEM-resident for the whole
-    cascade.  Right when all tables together fit comfortably in VMEM (the
-    common case: paper configs total a few hundred KiB).
+    cascade.  Right when the packed buffers fit the VMEM budget (nid and
+    the JSC designs; mnist's address matrix alone is ~44 MB).
   * :func:`lut_cascade_pallas` ``mode="streamed"`` — 2-D grid over
     (batch-tile x layer-unit-tile).  Tables and address matrices are cut
-    into per-phase ``unit_tile``-wide tiles and streamed HBM->VMEM by the
+    into ``unit_tile``-wide column tiles and streamed HBM->VMEM by the
     Pallas pipeline (the next phase's tiles DMA while the current phase
-    runs on the MXU — automatic double buffering), with the per-phase
-    write offsets scalar-prefetched via ``PrefetchScalarGridSpec`` and the
-    activation carried across phases in VMEM scratch guarded by
-    ``pl.when``.  Right when the packed tables outgrow the VMEM budget.
+    runs — automatic double buffering), with the per-phase write offsets
+    scalar-prefetched via ``PrefetchScalarGridSpec`` and the activation
+    carried across phases in VMEM scratch guarded by ``pl.when``.  Right
+    when the packed tables outgrow the VMEM budget.
 
 Shared algebra (docs/KERNELS.md has the full walkthrough):
 
-  * **Tables** for all layers are bit-packed into ONE buffer
+  * **Tables** for all layers are bit-packed into ONE plan buffer
     ``[total_units, max_entries]`` (int8/int16 when the largest beta
     allows, e.g. 1-bit layers pack 4x denser than int32), each layer a
     static row-slice.
@@ -37,9 +37,14 @@ Shared algebra (docs/KERNELS.md has the full walkthrough):
     contiguous mapping, duplicate fan-in indices just sum their weights).
     All values are integers below 2^24, so f32 MXU arithmetic is exact —
     planning enforces ``bits*F <= 24`` (paper configs max out at 12).
-  * **Lookup** is a one-hot x table contraction on the MXU (Pallas modes)
-    or a flat gather (XLA mode); padded rows/columns are zero everywhere,
-    so full-width padded matmuls stay exact.
+  * **Lane layout** (Pallas modes): the plan buffers are re-packed on the
+    host with every layer's units rounded up to whole 128-lane tiles
+    (:func:`lane_layout`), so every lane offset the kernels touch is
+    provably aligned, and the tables are transposed to ``[entries,
+    units]`` so units sit on lanes.
+  * **Lookup** is a select scan over entry rows on the VPU (Pallas
+    modes) or a flat gather (XLA mode); padded rows/columns are zero
+    everywhere, so full-width padded matmuls stay exact.
 
 Every ``pallas_call`` carries a :func:`cascade_cost_estimate` so XLA's
 scheduler sees the kernel's true arithmetic intensity.  All three paths
@@ -57,8 +62,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.lut_gather import fit_block_b
 
 Array = jax.Array
 
@@ -83,13 +86,96 @@ def is_v2_layers(layers: Sequence[Sequence[int]]) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# cost model shared by both Pallas modes
+# lane layout shared by both Pallas modes
+# ---------------------------------------------------------------------------
+
+LANE = 128      # TPU vreg lane width: lane-dim offsets and widths align to it
+SUBLANE = 8     # f32/int32 sublane count: the batch tile is a multiple of it
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def lane_layout(layers: Sequence[Sequence[int]], tile: int):
+    """Static lane layout of the Pallas kernels' packed buffers.
+
+    Every layer's units are rounded up to a multiple of ``tile`` and laid
+    out contiguously, so each layer starts at a ``tile``-aligned column
+    and the Mosaic compiler can prove every lane offset aligned.  Returns
+    ``(blocks, total, w0p, a_dim)``: per layer ``(in_rows, col, width)``
+    (its padded input width, column offset, padded unit width), the total
+    padded column count, the input width rounded up to ``LANE``, and the
+    activation width (the widest input)."""
+    w0p = _round_up(int(layers[0][0]), LANE)
+    blocks, col, rows = [], 0, w0p
+    for _, units, _, _, *_ in layers:
+        width = _round_up(int(units), tile)
+        blocks.append((rows, col, width))
+        col += width
+        rows = width
+    a_dim = max(r for r, _, _ in blocks)
+    return tuple(blocks), col, w0p, a_dim
+
+
+def pack_lanes(amat, tables, layers: Sequence[Sequence[int]], tile: int):
+    """Re-pack the plan's ``amat``/``tables`` into the lane layout.
+
+    Host-side (numpy) and done once per trace: the results are constants
+    of the jitted program.  Returns ``amat_k [a_dim, total]`` f32 and the
+    transposed tables cut into lane chunks, ``tab_k [total // chunk,
+    max_entries, chunk]`` int32 (``chunk = min(LANE, tile)``; entries on
+    sublanes, units on lanes, so a lookup reads one table row per entry
+    at lane offset 0 — Mosaic refuses a dynamic row load at any other)."""
+    try:
+        amat = np.asarray(amat, np.float32)
+        tables = np.asarray(tables)
+    except jax.errors.TracerArrayConversionError as e:
+        raise TypeError("lut_cascade_pallas needs concrete amat/tables "
+                        "(plan constants), not traced values") from e
+    blocks, total, _, a_dim = lane_layout(layers, tile)
+    amat_k = np.zeros((a_dim, total), np.float32)
+    tab_k = np.zeros((tables.shape[1], total), np.int32)
+    for (prev, units, _, off, *_), (_, col, _) in zip(layers, blocks):
+        amat_k[:prev, col:col + units] = amat[:prev, off:off + units]
+        tab_k[:, col:col + units] = tables[off:off + units].T
+    chunk = min(LANE, tile)
+    tab_k = tab_k.reshape(len(tab_k), total // chunk, chunk)
+    return amat_k, np.ascontiguousarray(tab_k.transpose(1, 0, 2))
+
+
+def _lookup(tab_ref, addr: Array, n_entries: int) -> Array:
+    """``out[b, u] = tab[addr[b, u], u]`` for one ``[entries, chunk]``
+    table tile.
+
+    A select scan over the table's entry rows: every step compares the
+    whole ``[block_b, chunk]`` address tile with one entry index and
+    keeps that entry's table row where it matches.  Units stay on lanes
+    throughout, so nothing is padded and the carry fits in vregs."""
+    def body(e, acc):
+        row = tab_ref[pl.ds(e, 1), :]                    # [1, chunk] int32
+        return jnp.where(addr == e, row, acc)
+    return jax.lax.fori_loop(0, n_entries, body,
+                             jnp.zeros(addr.shape, jnp.int32))
+
+
+def _address(h: Array, a: Array) -> Array:
+    # gather + address packing as ONE matmul.  Exact only as full-f32
+    # multiplies (ints < 2^24): HIGHEST forbids the MXU's default bf16
+    # input precision, which is exact merely to 2^8.
+    addr = jnp.dot(h, a, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+    return jnp.round(addr).astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# cost and VMEM model shared by both Pallas modes
 # ---------------------------------------------------------------------------
 
 def cascade_flops(layers: Sequence[Sequence[int]], batch: int) -> int:
-    """MXU flops of one cascade pass: per layer, the address-formation
-    matmul (2*B*prev*units) plus the one-hot lookup contraction
-    (2*B*units*entries)."""
+    """Work of one cascade pass: per layer, the address-formation matmul
+    (2*B*prev*units MXU flops) plus the lookup's select scan (2*B*units*
+    entries compare/select VPU ops)."""
     f = 0
     for prev, units, entries, _, *_ in layers:
         f += 2 * batch * prev * units + 2 * batch * units * entries
@@ -97,21 +183,18 @@ def cascade_flops(layers: Sequence[Sequence[int]], batch: int) -> int:
 
 
 def cascade_bytes(layers: Sequence[Sequence[int]], batch: int,
-                  table_itemsize: int, *, mode: str = "resident",
-                  block_b: int = 256) -> int:
-    """HBM bytes of one cascade pass.
+                  *, mode: str = "resident", block_b: int = 256,
+                  unit_tile: int = LANE) -> int:
+    """HBM bytes of one cascade pass over the lane-packed constants.
 
     Resident mode reads the packed buffers once; streamed mode re-streams
     the table/amat tiles for every batch tile (that re-read is the price
     of never holding the full table set in VMEM)."""
-    l4 = layers_v1(layers)
-    total_units = sum(u for _, u, _, _ in l4)
-    max_prev = max(p for p, _, _, _ in l4)
-    max_entries = max(e for _, _, e, _ in l4)
-    w0 = l4[0][0]
-    n_out = l4[-1][1]
-    const = max_prev * total_units * 4 + total_units * max_entries * table_itemsize
-    io = batch * w0 * 4 + batch * n_out * 4
+    tile = LANE if mode == "resident" else unit_tile
+    blocks, total, w0p, a_dim = lane_layout(layers, tile)
+    max_entries = max(int(e) for _, _, e, _, *_ in layers)
+    const = (a_dim + max_entries) * total * 4
+    io = batch * (w0p + blocks[-1][2]) * 4
     if mode == "streamed":
         n_bt = max(1, math.ceil(batch / block_b))
         return io + n_bt * const
@@ -119,42 +202,91 @@ def cascade_bytes(layers: Sequence[Sequence[int]], batch: int,
 
 
 def cascade_cost_estimate(layers: Sequence[Sequence[int]], batch: int,
-                          table_itemsize: int, *, mode: str = "resident",
-                          block_b: int = 256) -> pl.CostEstimate:
+                          *, mode: str = "resident", block_b: int = 256,
+                          unit_tile: int = LANE) -> pl.CostEstimate:
     """``pl.CostEstimate`` for one fused-cascade launch (both modes)."""
     return pl.CostEstimate(
         flops=cascade_flops(layers, batch),
-        bytes_accessed=cascade_bytes(layers, batch, table_itemsize,
-                                     mode=mode, block_b=block_b),
+        bytes_accessed=cascade_bytes(layers, batch, mode=mode,
+                                     block_b=block_b, unit_tile=unit_tile),
         transcendentals=0)
+
+
+def vmem_bytes(layers: Sequence[Sequence[int]], *, mode: str,
+               block_b: int, unit_tile: int = LANE) -> int:
+    """Upper bound on the VMEM the compiled kernel allocates, lane and
+    sublane padding in.
+
+    Counts every blocked operand double-buffered (constant index maps
+    included), both activation scratches, and the working values of one
+    unit chunk — the loaded ``[block_b, a_dim]`` activation plus its
+    split bf16 parts for the HIGHEST-precision matmul, and the
+    address/carry tiles.  Checked against the v5e compiler at 128-lane
+    tiles (tests/test_tpu_compile.py); wider streamed tiles allocate more
+    than this counts."""
+    tile = LANE if mode == "resident" else unit_tile
+    blocks, total, w0p, a_dim = lane_layout(layers, tile)
+    ents = _round_up(max(int(e) for _, _, e, _, *_ in layers), SUBLANE)
+    chunk = min(LANE, tile)
+    cols = total if mode == "resident" else unit_tile
+    io = 2 * block_b * (w0p + blocks[-1][2]) * 4
+    consts = 2 * (a_dim + ents) * cols * 4
+    scratch = 2 * block_b * a_dim * 4
+    work = block_b * a_dim * (4 + 3 * 2) + 4 * block_b * chunk * 4
+    return io + consts + scratch + work
 
 
 # ---------------------------------------------------------------------------
 # mode "resident": grid over batch, all tables VMEM-resident
 # ---------------------------------------------------------------------------
 
-def _resident_kernel(codes_ref, amat_ref, tables_ref, out_ref, *,
-                     layers: Tuple[LayerMeta, ...]):
-    """One batch tile through every layer; tables stay resident."""
-    h = codes_ref[...].astype(jnp.float32)               # [BB, W0]
-    for prev, units, entries, off in layers:
-        a = amat_ref[0:prev, off:off + units]            # [prev, U] f32
-        # gather + address packing as ONE matmul.  Exact only as full-f32
-        # multiplies (ints < 2^24): HIGHEST forbids the MXU's default bf16
-        # input precision, which is exact merely to 2^8.
-        addr = jnp.dot(h, a, preferred_element_type=jnp.float32,
-                       precision=jax.lax.Precision.HIGHEST)
-        addr_i = jnp.round(addr).astype(jnp.int32)       # [BB, U]
-        tab = tables_ref[off:off + units, 0:entries].astype(jnp.float32)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, entries), 2)
-        onehot = (addr_i[..., None] == iota).astype(jnp.float32)
-        out = jax.lax.dot_general(                       # [U, BB, 1]
-            onehot.transpose(1, 0, 2), tab[..., None],
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)
-        h = jnp.round(out[..., 0].T)                     # [BB, U] codes
-    out_ref[...] = h.astype(jnp.int32)
+def _resident_kernel(codes_ref, amat_ref, tab_ref, out_ref, h0_ref, h1_ref,
+                     *, blocks, entries: Tuple[int, ...], chunk: int):
+    """One batch tile through every layer; tables stay resident.
+
+    Layer ``l`` reads its input from scratch ``h[(l-1) % 2]`` (the codes
+    for layer 0) and writes its output codes chunk by chunk into
+    ``h[l % 2]`` (the output block for the last layer)."""
+    bufs = (h0_ref, h1_ref)
+    last = len(blocks) - 1
+    for l, ((rows, col, width), n_e) in enumerate(zip(blocks, entries)):
+        src = codes_ref if l == 0 else bufs[(l - 1) % 2]
+        h = src[:, 0:rows].astype(jnp.float32)               # [BB, rows]
+        for c0 in range(0, width, chunk):
+            addr = _address(h, amat_ref[0:rows, col + c0:col + c0 + chunk])
+            codes = _lookup(tab_ref.at[(col + c0) // chunk], addr, n_e)
+            if l == last:
+                out_ref[:, c0:c0 + chunk] = codes
+            else:
+                bufs[l % 2][:, c0:c0 + chunk] = codes.astype(jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("layers", "block_b",
+                                             "interpret"))
+def _resident_call(codes_p: Array, amat_k: Array, tab_k: Array, *,
+                   layers: Tuple[LayerMeta, ...], block_b: int,
+                   interpret: bool) -> Array:
+    bb, w0p = codes_p.shape
+    blocks, total, _, a_dim = lane_layout(layers, LANE)
+    n_out_p = blocks[-1][2]
+    return pl.pallas_call(
+        functools.partial(_resident_kernel, blocks=blocks,
+                          entries=tuple(e for _, _, e, _ in layers),
+                          chunk=LANE),
+        grid=(bb // block_b,),
+        in_specs=[
+            pl.BlockSpec((block_b, w0p), lambda i: (i, 0)),
+            pl.BlockSpec(amat_k.shape, lambda i: (0, 0)),
+            pl.BlockSpec(tab_k.shape, lambda i: (0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((block_b, n_out_p), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bb, n_out_p), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((block_b, a_dim), jnp.float32),
+                        pltpu.VMEM((block_b, a_dim), jnp.float32)],
+        cost_estimate=cascade_cost_estimate(layers, bb, mode="resident",
+                                            block_b=block_b),
+        interpret=interpret,
+    )(codes_p, amat_k, tab_k)
 
 
 # ---------------------------------------------------------------------------
@@ -164,202 +296,154 @@ def _resident_kernel(codes_ref, amat_ref, tables_ref, out_ref, *,
 def _phase_layout(layers: Tuple[LayerMeta, ...], unit_tile: int):
     """Static phase plan for the streamed kernel.
 
-    A *phase* is one (layer, unit-tile) pair; phases run sequentially on
-    the inner grid axis.  Returns the per-phase scalar-prefetch arrays
-    (within-layer column offset + start/end/output flags) and the padded
-    activation width ``a_dim`` (max of the input width and every layer's
-    tile-rounded unit count — the VMEM scratch that carries activations
-    between phases)."""
-    cols, starts, ends, outs = [], [], [], []
-    src = []                                 # (row_lo, row_hi) per phase
-    last = len(layers) - 1
-    for li, (_, units, _, off) in enumerate(layers):
-        n_t = math.ceil(units / unit_tile)
+    A *phase* is one ``unit_tile``-wide column block of the lane layout,
+    i.e. one (layer, unit-tile) pair; phases run sequentially on the inner
+    grid axis, and phase ``j`` streams column block ``j`` of the packed
+    buffers.  Returns the per-phase scalar-prefetch arrays: the column
+    offset within its layer, and the layer-end and output flags."""
+    cols, ends, outs = [], [], []
+    blocks, _, _, _ = lane_layout(layers, unit_tile)
+    last = len(blocks) - 1
+    for li, (_, _, width) in enumerate(blocks):
+        n_t = width // unit_tile
         for c in range(n_t):
             cols.append(c * unit_tile)
-            starts.append(1 if c == 0 else 0)
             ends.append(1 if c == n_t - 1 else 0)
             outs.append(1 if li == last else 0)
-            lo = off + c * unit_tile
-            src.append((lo, min(lo + unit_tile, off + units)))
-    a_dim = max([layers[0][0]] +
-                [math.ceil(u / unit_tile) * unit_tile
-                 for _, u, _, _ in layers])
-    return (np.asarray(cols, np.int32), np.asarray(starts, np.int32),
-            np.asarray(ends, np.int32), np.asarray(outs, np.int32),
-            src, a_dim)
+    return (np.asarray(cols, np.int32), np.asarray(ends, np.int32),
+            np.asarray(outs, np.int32))
 
 
-def _streamed_kernel(col_ref, start_ref, end_ref, emit_ref,  # scalar prefetch
+def _streamed_kernel(col_ref, end_ref, emit_ref,            # scalar prefetch
                      codes_ref, amat_ref, tab_ref, out_ref,
-                     h_ref, hn_ref, *, w0: int, block_b: int,
-                     a_dim: int, unit_tile: int, max_entries: int):
+                     h_ref, hn_ref, *, w0p: int, unit_tile: int,
+                     chunk: int, n_entries: int):
     """One (batch-tile, phase) grid step.
 
     ``h_ref`` holds the current layer's *input* codes (f32, zero-padded to
-    ``a_dim``); ``hn_ref`` accumulates the layer's output tile by tile.
-    Both live in VMEM scratch and persist across the sequential phase
-    axis.  ``amat_ref``/``tab_ref`` see only this phase's tile — the
-    Pallas pipeline fetches phase j+1's tiles while phase j computes."""
+    ``a_dim``); ``hn_ref`` collects the layer's output tile by tile.  Both
+    live in VMEM scratch and persist across the sequential phase axis.
+    ``amat_ref``/``tab_ref`` see only this phase's tile — the Pallas
+    pipeline fetches phase j+1's tiles while phase j computes."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _load_input():                        # first phase of the cascade
-        h_ref[...] = jnp.zeros((block_b, a_dim), jnp.float32)
-        h_ref[:, 0:w0] = codes_ref[...].astype(jnp.float32)
-
-    @pl.when(start_ref[j] == 1)
-    def _layer_start():                       # fresh accumulator per layer
-        hn_ref[...] = jnp.zeros((block_b, a_dim), jnp.float32)
+        h_ref[...] = jnp.zeros(h_ref.shape, jnp.float32)
+        hn_ref[...] = jnp.zeros(hn_ref.shape, jnp.float32)
+        h_ref[:, 0:w0p] = codes_ref[...].astype(jnp.float32)
 
     # address formation over the FULL padded width: padded h columns and
     # padded amat rows are both zero, so the wide matmul is exact.
-    a = amat_ref[0]                                      # [A, U_t] f32
-    addr = jnp.dot(h_ref[...], a, preferred_element_type=jnp.float32,
-                   precision=jax.lax.Precision.HIGHEST)
-    addr_i = jnp.round(addr).astype(jnp.int32)           # [BB, U_t]
-    tab = tab_ref[0].astype(jnp.float32)                 # [U_t, E] f32
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, max_entries), 2)
-    onehot = (addr_i[..., None] == iota).astype(jnp.float32)
-    out = jax.lax.dot_general(                           # [U_t, BB, 1]
-        onehot.transpose(1, 0, 2), tab[..., None],
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)
-    codes_out = jnp.round(out[..., 0].T)                 # [BB, U_t] f32
-
+    h = h_ref[...]
     col = col_ref[j]
-    hn_ref[:, pl.ds(col, unit_tile)] = codes_out
+    emit = emit_ref[j] == 1
+    for c0 in range(0, unit_tile, chunk):
+        addr = _address(h, amat_ref[:, c0:c0 + chunk])
+        codes = _lookup(tab_ref.at[c0 // chunk], addr, n_entries)
+        at = pl.ds(pl.multiple_of(col + c0, chunk), chunk)
+        hn_ref[:, at] = codes.astype(jnp.float32)
+
+        @pl.when(emit)
+        def _emit():                          # final layer: write codes out
+            out_ref[:, at] = codes
 
     @pl.when(end_ref[j] == 1)
     def _layer_end():                         # output becomes next input
         h_ref[...] = hn_ref[...]
 
-    @pl.when(emit_ref[j] == 1)
-    def _emit():                              # final layer: write codes out
-        out_ref[:, pl.ds(col, unit_tile)] = codes_out.astype(jnp.int32)
 
-
-def _streamed_call(codes_p: Array, amat: Array, tables: Array,
+@functools.partial(jax.jit, static_argnames=("layers", "block_b",
+                                             "unit_tile", "interpret"))
+def _streamed_call(codes_p: Array, amat_k: Array, tab_k: Array, *,
                    layers: Tuple[LayerMeta, ...], block_b: int,
                    unit_tile: int, interpret: bool) -> Array:
-    bb, w0 = codes_p.shape
-    cols, starts, ends, outs, src, a_dim = _phase_layout(layers, unit_tile)
-    n_phases = len(cols)
-    max_entries = tables.shape[1]
-    n_out = layers[-1][1]
-    n_out_pad = math.ceil(n_out / unit_tile) * unit_tile
-
-    # cut the flat plan buffers into per-phase tiles (static slices; this
-    # runs inside jit so XLA fuses the restacking into the launch prologue)
-    amat_p = jnp.pad(amat, ((0, a_dim - amat.shape[0]), (0, 0)))
-    a_tiles = jnp.stack([
-        jnp.pad(amat_p[:, lo:hi], ((0, 0), (0, unit_tile - (hi - lo))))
-        for lo, hi in src])                              # [P, A, U_t]
-    t_tiles = jnp.stack([
-        jnp.pad(tables[lo:hi], ((0, unit_tile - (hi - lo)), (0, 0)))
-        for lo, hi in src])                              # [P, U_t, E]
-
+    bb, w0p = codes_p.shape
+    blocks, _, _, a_dim = lane_layout(layers, unit_tile)
+    cols, ends, outs = _phase_layout(layers, unit_tile)
+    n_entries = tab_k.shape[1]
+    chunk = min(LANE, unit_tile)
+    n_out_p = blocks[-1][2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(bb // block_b, n_phases),
+        num_scalar_prefetch=3,
+        grid=(bb // block_b, len(cols)),
         in_specs=[
-            pl.BlockSpec((block_b, w0), lambda i, j, *_: (i, 0)),
-            pl.BlockSpec((1, a_dim, unit_tile), lambda i, j, *_: (j, 0, 0)),
-            pl.BlockSpec((1, unit_tile, max_entries),
+            pl.BlockSpec((block_b, w0p), lambda i, j, *_: (i, 0)),
+            pl.BlockSpec((a_dim, unit_tile), lambda i, j, *_: (0, j)),
+            pl.BlockSpec((unit_tile // chunk, n_entries, chunk),
                          lambda i, j, *_: (j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_b, n_out_pad), lambda i, j, *_: (i, 0)),
+        out_specs=pl.BlockSpec((block_b, n_out_p), lambda i, j, *_: (i, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_b, a_dim), jnp.float32),   # h (layer input)
-            pltpu.VMEM((block_b, a_dim), jnp.float32),   # h_next (output acc)
+            pltpu.VMEM((block_b, a_dim), jnp.float32),   # h_next (output)
         ],
     )
-    out = pl.pallas_call(
-        functools.partial(_streamed_kernel, w0=w0, block_b=block_b,
-                          a_dim=a_dim, unit_tile=unit_tile,
-                          max_entries=max_entries),
+    return pl.pallas_call(
+        functools.partial(_streamed_kernel, w0p=w0p, unit_tile=unit_tile,
+                          chunk=chunk, n_entries=n_entries),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bb, n_out_pad), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((bb, n_out_p), jnp.int32),
         cost_estimate=cascade_cost_estimate(
-            layers, bb, tables.dtype.itemsize, mode="streamed",
-            block_b=block_b),
+            layers, bb, mode="streamed", block_b=block_b,
+            unit_tile=unit_tile),
         interpret=interpret,
-    )(jnp.asarray(cols), jnp.asarray(starts), jnp.asarray(ends),
-      jnp.asarray(outs), codes_p, a_tiles, t_tiles)
-    return out[:, :n_out]
+    )(jnp.asarray(cols), jnp.asarray(ends), jnp.asarray(outs),
+      codes_p, amat_k, tab_k)
 
 
 # ---------------------------------------------------------------------------
 # public entry points
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("layers", "block_b", "mode",
-                                             "unit_tile", "interpret"))
-def lut_cascade_pallas(codes: Array, amat: Array, tables: Array, *,
+def lut_cascade_pallas(codes: Array, amat, tables, *,
                        layers: Tuple[LayerMeta, ...], block_b: int = 256,
-                       mode: str = "resident", unit_tile: int = 8,
+                       mode: str = "resident", unit_tile: int = LANE,
                        interpret: bool = True) -> Array:
     """Run the whole folded cascade in a single ``pallas_call``.
 
     codes:  [batch, in_features] int32 input codes.
     amat:   [max_prev, total_units] f32 — per-layer address-formation
             matrices packed block-wise (layer l occupies rows [0:prev_l],
-            cols [off_l : off_l+units_l]).
+            cols [off_l : off_l+units_l]).  Concrete (a plan constant).
     tables: [total_units, max_entries] int — per-layer tables packed along
-            rows at the same offsets (narrow dtype allowed).
+            rows at the same offsets (narrow dtype allowed).  Concrete.
     layers: static per-layer metadata, ``(prev, units, entries, off)``
             4-tuples or the v2 7-tuples (extra fields ignored here).
     mode:   "resident" (1-D batch grid, tables VMEM-resident) or
             "streamed" (2-D batch x phase grid, tiles streamed HBM->VMEM
             with scalar-prefetched offsets).  ``unit_tile`` sets the
-            streamed tile width; the autotuner picks both.
+            streamed tile width (a multiple of ``LANE`` when compiled);
+            the autotuner picks both.
     """
     if mode not in ("resident", "streamed"):
         raise ValueError(f"unknown lut_cascade mode {mode!r}")
+    if not interpret and (mode == "streamed" and unit_tile % LANE
+                          or block_b % SUBLANE):
+        raise ValueError(
+            f"compiled lut_cascade needs unit_tile % {LANE} == 0 and "
+            f"block_b % {SUBLANE} == 0, got {unit_tile}, {block_b}")
     layers = layers_v1(layers)
     batch = codes.shape[0]
     # never tile wider than the batch itself (rounded up to a power of two,
     # floored at the sublane count): under batch-sharded placement each
-    # device sees batch/n rows, and padding those to a full 256-row tile
-    # would waste most of the kernel's work
-    block_b = min(block_b, max(8, 1 << (batch - 1).bit_length()))
-    if mode == "resident":
-        # the one-hot tile is the VMEM high-water mark; shrink to fit
-        worst = max(u * t for _, u, t, _ in layers)
-        block_b = fit_block_b(block_b, worst * 4)
-    else:
-        # high-water: one-hot [BB, U_t, E] + the two activation scratches
-        _, _, _, _, _, a_dim = _phase_layout(layers, unit_tile)
-        per_row = (unit_tile * tables.shape[1] + 2 * a_dim) * 4
-        block_b = fit_block_b(block_b, per_row)
-
-    pb = (-batch) % block_b
-    codes_p = jnp.pad(codes, ((0, pb), (0, 0)))  # zero rows: valid addresses
-    bb = codes_p.shape[0]
-    n_out = layers[-1][1]
-
+    # device sees batch/n rows, and padding those to a full tile would
+    # waste most of the kernel's work
+    block_b = min(block_b, max(SUBLANE, 1 << (batch - 1).bit_length()))
+    tile = LANE if mode == "resident" else unit_tile
+    amat_k, tab_k = pack_lanes(amat, tables, layers, tile)
+    _, _, w0p, _ = lane_layout(layers, tile)
+    # zero rows and columns: valid addresses, multiplied by zero amat rows
+    codes_p = jnp.pad(codes.astype(jnp.int32),
+                      ((0, (-batch) % block_b), (0, w0p - codes.shape[1])))
     if mode == "streamed":
-        out = _streamed_call(codes_p, amat, tables, layers, block_b,
-                             unit_tile, interpret)
-        return out[:batch]
-
-    out = pl.pallas_call(
-        functools.partial(_resident_kernel, layers=layers),
-        grid=(bb // block_b,),
-        in_specs=[
-            pl.BlockSpec((block_b, codes.shape[1]), lambda i: (i, 0)),
-            pl.BlockSpec(amat.shape, lambda i: (0, 0)),
-            pl.BlockSpec(tables.shape, lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, n_out), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bb, n_out), jnp.int32),
-        cost_estimate=cascade_cost_estimate(
-            layers, bb, tables.dtype.itemsize, mode="resident",
-            block_b=block_b),
-        interpret=interpret,
-    )(codes_p, amat, tables)
-    return out[:batch]
+        out = _streamed_call(codes_p, amat_k, tab_k, layers=layers,
+                             block_b=block_b, unit_tile=unit_tile,
+                             interpret=interpret)
+    else:
+        out = _resident_call(codes_p, amat_k, tab_k, layers=layers,
+                             block_b=block_b, interpret=interpret)
+    return out[:batch, :layers[-1][1]]
 
 
 @functools.partial(jax.jit, static_argnames=("layers",))

@@ -1,19 +1,21 @@
-"""Pallas TPU kernel: batched L-LUT lookup as a one-hot MXU matmul.
+"""Pallas TPU kernel: batched per-layer L-LUT lookup.
 
 The paper's folded inference is a cascade of table lookups.  On an FPGA the
 lookup is free soft logic; on TPU a naive row-gather of tiny table rows is
-HBM-latency-bound while the MXU idles.  For the small tables the paper
-actually uses (2^{beta*F} <= 4096 entries) we instead materialize a one-hot
-matrix in VMEM and contract it with the table on the MXU:
+HBM-latency-bound.  This kernel keeps a tile of the (transposed) table in
+VMEM and resolves every address of a ``[block_b, 128]`` tile with a select
+scan over the table's entry rows:
 
-    out[b, u] = sum_t  onehot(addr[b, u])[t] * table[u, t]
+    out[b, u] = table[u, e]  for the one entry e == addr[b, u]
 
-which is a [BB x T] @ [T x 1] batched matmul per unit block — dense,
-layout-friendly, and fully pipelined.  The grid tiles (batch, units); each
-step keeps its (addr tile, table tile) resident in VMEM.
+Units sit on lanes and entries on sublanes, so every block is lane-dense
+(``128``-wide) and no operand is padded.  The grid tiles (batch, units);
+each step holds one (addr tile, table tile) pair in VMEM.  The fused
+cascade (``lut_cascade``) uses the same lookup inside its launch.
 
 Validated in interpret mode against ``ref.lut_lookup_ref`` (exact integer
-equality) by tests/test_kernels.py.
+equality) by tests/test_kernels.py and compiled for a TPU v5e by
+tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -23,68 +25,42 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.lut_cascade import LANE, SUBLANE, _lookup
+
 Array = jax.Array
 
-VMEM_TILE_BUDGET = 4 * 2 ** 20  # ~4 MiB: the one-hot tile high-water mark
+
+def _lut_kernel(addr_ref, table_ref, out_ref, *, n_entries: int):
+    out_ref[...] = _lookup(table_ref, addr_ref[...], n_entries)
 
 
-def fit_block_b(block_b: int, per_row_bytes: int,
-                budget: int = VMEM_TILE_BUDGET, floor: int = 8) -> int:
-    """Halve ``block_b`` until the dominant per-step tile fits the VMEM
-    budget (shared by this kernel and the fused cascade in lut_cascade)."""
-    while block_b * per_row_bytes > budget and block_b > floor:
-        block_b //= 2
-    return block_b
-
-
-def _lut_kernel(addr_ref, table_ref, out_ref):
-    addr = addr_ref[...]                       # [BB, BU] int32
-    table = table_ref[...]                     # [BU, T]  int32
-    t = table.shape[-1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, t), 2)
-    onehot = (addr[..., None] == iota).astype(jnp.float32)   # [BB, BU, T]
-    oh = onehot.transpose(1, 0, 2)                           # [BU, BB, T]
-    tb = table.astype(jnp.float32)[..., None]                # [BU, T, 1]
-    # batched over the unit axis; contraction over the T entries -> MXU.
-    out = jax.lax.dot_general(
-        oh, tb,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                                        # [BU, BB, 1]
-    out_ref[...] = jnp.round(out[..., 0].T).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "block_u", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def lut_lookup_pallas(table: Array, addr: Array, *, block_b: int = 256,
-                      block_u: int = 8, interpret: bool = True) -> Array:
+                      interpret: bool = True) -> Array:
     """table: [units, entries] int32, addr: [batch, units] int32.
 
-    Block sizes target VMEM: a (block_b, block_u, entries) f32 one-hot tile
-    at defaults with 4096 entries is 256*8*4096*4 B = 32 MiB ... too big, so
-    the wrapper shrinks block_b to keep the tile under ~4 MiB.
+    VMEM per grid step is the double-buffered ``[block_b, 128]`` address
+    and output tiles plus the ``[entries, 128]`` table tile: at 4096
+    entries and the default ``block_b`` about 4.5 MiB.
     """
     batch, units = addr.shape
     entries = table.shape[-1]
-    # keep the one-hot tile <= ~4 MiB of VMEM
-    block_b = fit_block_b(block_b, block_u * entries * 4)
-    while block_b * block_u * entries * 4 > VMEM_TILE_BUDGET and block_u > 1:
-        block_u //= 2
-
+    block_b = min(block_b, max(SUBLANE, 1 << (batch - 1).bit_length()))
     pb = (-batch) % block_b
-    pu = (-units) % block_u
-    addr_p = jnp.pad(addr, ((0, pb), (0, pu)))
-    table_p = jnp.pad(table, ((0, pu), (0, 0)))
+    pu = (-units) % LANE
+    addr_p = jnp.pad(addr.astype(jnp.int32), ((0, pb), (0, pu)))
+    table_t = jnp.pad(table.astype(jnp.int32), ((0, pu), (0, 0))).T
     bb, uu = addr_p.shape
 
     out = pl.pallas_call(
-        _lut_kernel,
-        grid=(bb // block_b, uu // block_u),
+        functools.partial(_lut_kernel, n_entries=entries),
+        grid=(bb // block_b, uu // LANE),
         in_specs=[
-            pl.BlockSpec((block_b, block_u), lambda i, j: (i, j)),
-            pl.BlockSpec((block_u, entries), lambda i, j: (j, 0)),
+            pl.BlockSpec((block_b, LANE), lambda i, j: (i, j)),
+            pl.BlockSpec((entries, LANE), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_b, block_u), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block_b, LANE), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bb, uu), jnp.int32),
         interpret=interpret,
-    )(addr_p, table_p)
+    )(addr_p, table_t)
     return out[:batch, :units]

@@ -11,10 +11,10 @@ builds on):
   * ``unit_affine``: einsum fallback vs the batched Pallas stage.
   * ``flash_attention``: jnp scan fallback (models/attention.py) vs Pallas.
 
-Pallas interpret mode is resolved in ONE place — :func:`pallas_interpret`,
-controlled by ``REPRO_PALLAS_INTERPRET`` ("1" force interpret, "0" force
-compiled, unset/"auto" = interpret unless running on TPU) — so TPU runs
-flip to compiled kernels without editing call sites.
+Pallas interpret mode is resolved in ONE place — :func:`pallas_interpret`:
+interpret everywhere except on a TPU backend, where kernels always
+compile (no environment switch can hide the device).  Tests flip it
+in-process with :func:`set_pallas_interpret`.
 
 The LM substrate lowers through the jnp paths by default so the multi-pod
 dry-run exercises plain XLA collectives; kernels are enabled per-config for
@@ -22,7 +22,6 @@ real TPU runs.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -48,19 +47,11 @@ def on_tpu() -> bool:
 
 
 def pallas_interpret() -> bool:
-    """The single source of truth for Pallas interpret mode.
-
-    Priority: :func:`set_pallas_interpret` override, then the
-    ``REPRO_PALLAS_INTERPRET`` env var ("1"/"0"), then auto (interpret
-    everywhere except on a real TPU backend).
-    """
+    """The single source of truth for Pallas interpret mode: the
+    :func:`set_pallas_interpret` override, else interpret everywhere
+    except on a TPU backend."""
     if _INTERPRET_OVERRIDE is not None:
         return _INTERPRET_OVERRIDE
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "auto").lower()
-    if env in ("1", "true", "yes"):
-        return True
-    if env in ("0", "false", "no"):
-        return False
     return not on_tpu()
 
 

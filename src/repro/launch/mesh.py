@@ -13,7 +13,13 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    # Auto axes: XLA's partitioner places the intermediates, as the code
+    # built on these meshes expects (jax.make_mesh defaults to Explicit)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -27,17 +33,17 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     if override:
         shape = tuple(int(x) for x in override.split(","))
         assert len(shape) == len(axes), (shape, axes)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> Mesh:
     """1x1 (data, model) mesh on whatever single device is present —
     used by smoke tests and CPU examples so the same pjit code paths run."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_serving_mesh(devices: int = 0) -> Mesh:

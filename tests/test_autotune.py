@@ -34,23 +34,13 @@ def _compiled(task="nid", seed=0):
     return pipeline.compile_network(params, cfg)
 
 
-def _layers(cfg):
-    layers, off = [], 0
-    for l, spec in enumerate(cfg.layers):
-        layers.append((cfg.prev_width(l), spec.units,
-                       2 ** (cfg.in_bits(l) * spec.fan_in), off,
-                       spec.fan_in, cfg.in_bits(l), int(spec.assemble)))
-        off += spec.units
-    return layers
-
-
 # ---------------------------------------------------------------------------
 # roofline model
 # ---------------------------------------------------------------------------
 
 def test_default_tuning_is_sane_and_feasible():
-    layers = _layers(paper_tasks.reduced("nid"))
-    t = autotune.default_tuning(layers, table_itemsize=1)
+    layers = autotune.config_layers(paper_tasks.reduced("nid"))
+    t = autotune.default_tuning(layers)
     assert t.source == "default"
     assert t.impl is None                      # auto: Pallas on TPU, XLA off
     assert t.mode in ("resident", "streamed")
@@ -59,9 +49,8 @@ def test_default_tuning_is_sane_and_feasible():
 
 
 def test_roofline_candidates_cover_grid_and_mark_vmem():
-    layers = _layers(paper_tasks.reduced("jsc"))
-    rows = autotune.roofline_candidates(layers, table_itemsize=1,
-                                        device="tpu")
+    layers = autotune.config_layers(paper_tasks.reduced("jsc"))
+    rows = autotune.roofline_candidates(layers, device="TPU v5 lite")
     n_expected = len(autotune.BLOCK_B_CANDIDATES) * (
         1 + len(autotune.UNIT_TILE_CANDIDATES))
     assert len(rows) == n_expected
@@ -77,16 +66,54 @@ def test_pick_tuning_streams_when_tables_exceed_vmem():
     must not pick a resident candidate that cannot fit."""
     # one layer, 2^14 entries x 4096 units x 4B = 256 MiB resident
     layers = [(4096 * 7, 4096, 2 ** 14, 0, 7, 2, 1)]
-    t = autotune.pick_tuning(layers, table_itemsize=4, device="cpu")
+    t = autotune.pick_tuning(layers, device="cpu")
     assert t.mode == "streamed"
-    rows = autotune.roofline_candidates(layers, table_itemsize=4,
-                                        device="cpu")
+    rows = autotune.roofline_candidates(layers, device="cpu")
     assert not any(r["fits_vmem"] for r in rows if r["mode"] == "resident")
 
 
+def test_device_kind_raises_on_unknown_kind():
+    """The device table is keyed by JAX's device_kind; a device without a
+    row gets an error, never another device's peaks or VMEM size."""
+    assert autotune.device_kind() == jax.devices()[0].device_kind
+    assert autotune.device_kind("TPU v5 lite") == "TPU v5 lite"
+    layers = autotune.config_layers(paper_tasks.reduced("nid"))
+    for kind in ("tpu", "gpu", "TPU v9 imaginary"):
+        with pytest.raises(ValueError, match="no device model"):
+            autotune.device_kind(kind)
+        with pytest.raises(ValueError, match="no device model"):
+            autotune.pick_tuning(layers, device=kind)
+    v5e = autotune.DEVICE_MODELS["TPU v5 lite"]
+    assert v5e["vmem_bytes"] == 16 * 2 ** 20 and v5e["source"]
+
+
+def test_foreign_device_tuning_is_repicked():
+    """A plan whose tuning was picked for another device kind runs with
+    (and records) this device's pick, with unchanged codes."""
+    compiled = _compiled()
+    x = np.asarray(jax.random.uniform(jax.random.PRNGKey(2),
+                                      (65, compiled.cfg.in_features),
+                                      minval=-1.0, maxval=1.0))
+    ref = np.asarray(compiled.predict_codes(x, backend="take"))
+    plan = compiled.compile_backend("fused").plan
+    here = KernelTuning.from_meta(plan.meta["tuning"])
+    assert here.device_kind == jax.devices()[0].device_kind
+    plan.meta["tuning"] = KernelTuning(
+        impl="pallas", mode="streamed", block_b=64, source="measured",
+        device_kind="TPU v5 lite").to_meta()
+    compiled._executors.clear()
+    np.testing.assert_array_equal(
+        np.asarray(compiled.predict_codes(x, backend="fused")), ref)
+    repicked = KernelTuning.from_meta(compiled._plans["fused"].meta["tuning"])
+    assert repicked == autotune.pick_tuning(
+        plan.meta["layers"], device=here.device_kind,
+        table_dtype=plan.meta["table_dtype"])
+
+
 def test_kernel_tuning_meta_round_trip():
-    t = KernelTuning(impl="xla", mode="streamed", block_b=128, unit_tile=16,
-                     table_dtype="int8", source="measured")
+    t = KernelTuning(impl="xla", mode="streamed", block_b=128, unit_tile=256,
+                     table_dtype="int8", source="measured",
+                     device_kind="TPU v5 lite")
     assert KernelTuning.from_meta(t.to_meta()) == t
     assert KernelTuning.from_meta(None) == KernelTuning()
     # unknown keys from a newer schema are dropped, not fatal
@@ -95,7 +122,7 @@ def test_kernel_tuning_meta_round_trip():
 
 
 def test_choice_table_covers_all_tasks_and_devices():
-    doc = autotune.choice_table(devices=("cpu", "tpu"))
+    doc = autotune.choice_table(devices=("cpu", "TPU v5 lite"))
     tasks = {c["task"] for c in doc["choices"]}
     assert tasks == set(paper_tasks.TASKS)
     assert all(c["tuning"]["block_b"] in autotune.BLOCK_B_CANDIDATES
@@ -160,7 +187,8 @@ def test_tuned_plan_round_trips_through_artifact(tmp_path):
     compiled.save(str(path))
     reloaded = CompiledLUTNetwork.load(str(path))
     t = KernelTuning.from_meta(reloaded._plans["fused"].meta["tuning"])
-    assert t == KernelTuning(impl="xla", block_b=128, source="measured")
+    assert t == KernelTuning(impl="xla", block_b=128, source="measured",
+                             device_kind="cpu")
     for be in backends.available():
         np.testing.assert_array_equal(
             np.asarray(reloaded.predict_codes(x, backend=be)), ref,

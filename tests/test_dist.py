@@ -72,6 +72,7 @@ def test_pipeline_parallel_matches_reference():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh
         from repro.dist.pipeline import make_pipelined_fn
+        from repro.launch.mesh import make_mesh
 
         n_stages, layers_per_stage = 4, 2
         L = n_stages * layers_per_stage
@@ -91,7 +92,7 @@ def test_pipeline_parallel_matches_reference():
             return h
         ref = jax.vmap(ref_fwd)(xs)
 
-        mesh = jax.make_mesh((n_stages,), ("pipe",))
+        mesh = make_mesh((n_stages,), ("pipe",))
         fn = make_pipelined_fn(layer_fn, mesh, axis_name="pipe",
                                n_stages=n_stages,
                                layers_per_stage=layers_per_stage)
@@ -108,11 +109,11 @@ def test_pipeline_parallel_matches_reference():
 def test_compressed_psum_subprocess():
     out = run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import PartitionSpec as P
         from repro.dist import compress
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = make_mesh((8,), ("d",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         err = jnp.zeros((8, 64))
 
@@ -120,8 +121,8 @@ def test_compressed_psum_subprocess():
             out, ne = compress.compressed_psum({"g": g[0]}, {"g": e[0]}, "d")
             return out["g"][None], ne["g"][None]
 
-        fn = shard_map(f, mesh=mesh, in_specs=(P("d"), P("d")),
-                       out_specs=(P("d"), P("d")), check_rep=False)
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P("d"), P("d")),
+                           out_specs=(P("d"), P("d")), check_vma=False)
         out, ne = fn(g, err)
         ref = jnp.mean(g, axis=0)
         # every shard holds the (approximate) mean

@@ -197,6 +197,7 @@ def test_lut_cascade_xla_matches_oracle(task):
 
 @pytest.mark.parametrize("mode,unit_tile", [
     ("resident", 8), ("streamed", 4), ("streamed", 8), ("streamed", 16),
+    ("streamed", 128), ("streamed", 256),
 ])
 def test_lut_cascade_pallas_modes_match_oracle(mode, unit_tile):
     """Resident and streamed Pallas tilings (interpret mode), ragged batch
