@@ -14,10 +14,10 @@ Three sweeps over the serving surface (DESIGN.md §3, docs/PERF_TUNING.md):
     ``fused_margin`` so a drift inside the margin stays visible.
     Hard-checked here for blocks >= 256 and by the ``kernel`` perf-gate
     suite.
-  * **engine**: rows/s and p50/p99 tick latency of the micro-batching
-    engine, synchronous (``depth=1``) vs async double-buffered
-    (``depth=2``).  ``async_speedup`` is the headline: dispatch-ahead
-    must beat dispatch-and-wait at block >= 256.
+  * **engine**: rows/s of the micro-batching engine, synchronous
+    (``depth=1``) vs async double-buffered (``depth=2``).
+    ``async_speedup`` is the headline: dispatch-ahead must beat
+    dispatch-and-wait at block >= 256.
   * **mesh**: strong-scaling rows/s of the batch-sharded planned executor
     across 1/2/4-way meshes at a FIXED ``mesh_rows`` batch (CPU devices
     via ``--xla_force_host_platform_device_count``, requested *before*
@@ -110,8 +110,7 @@ def _best_rows_per_s(make_engines, x, reps: int):
             t0 = time.perf_counter()
             eng.run(x)
             rate = len(x) / (time.perf_counter() - t0)
-            if mode not in best or rate > best[mode][0]:
-                best[mode] = (rate, eng.stats)
+            best[mode] = max(rate, best.get(mode, 0.0))
     return best
 
 
@@ -205,13 +204,8 @@ def sweep(task: str = "nid", blocks=(64, 256, 1024),
             best = _best_rows_per_s(
                 {"sync": _make(block, name, 1),
                  "async": _make(block, name, 2)}, xe, reps)
-            for mode, (rate, stats) in best.items():
-                s = stats.summary()   # the supported stats surface
-                cell[mode] = {
-                    "rows_per_s": round(rate, 1),
-                    "p50_tick_us": s["p50_tick_us"],
-                    "p99_tick_us": s["p99_tick_us"],
-                }
+            for mode, rate in best.items():
+                cell[mode] = {"rows_per_s": round(rate, 1)}
             cell["async_speedup"] = round(
                 cell["async"]["rows_per_s"] / cell["sync"]["rows_per_s"], 3)
             results["engine"].append(cell)
@@ -265,12 +259,10 @@ def main() -> None:
     for c in results["kernel"]:
         print(f"{c['backend']},{c['block']},{c['rows_per_s']},"
               f"{c['fused_fastest']}")
-    print("backend,block,sync_rows_per_s,async_rows_per_s,async_speedup,"
-          "async_p50_us,async_p99_us")
+    print("backend,block,sync_rows_per_s,async_rows_per_s,async_speedup")
     for c in results["engine"]:
         print(f"{c['backend']},{c['block']},{c['sync']['rows_per_s']},"
-              f"{c['async']['rows_per_s']},{c['async_speedup']},"
-              f"{c['async']['p50_tick_us']},{c['async']['p99_tick_us']}")
+              f"{c['async']['rows_per_s']},{c['async_speedup']}")
     print("backend,mesh,rows_per_s,bit_identical")
     for c in results["mesh"]:
         print(f"{c['backend']},{c['mesh']},{c['rows_per_s']},"

@@ -47,9 +47,11 @@ Shared algebra (docs/KERNELS.md has the full walkthrough):
     everywhere, so full-width padded matmuls stay exact.
 
 Every ``pallas_call`` carries a :func:`cascade_cost_estimate` so XLA's
-scheduler sees the kernel's true arithmetic intensity.  All three paths
-are validated bit-exact against the per-layer 'take' oracle over every
-paper task config by tests/test_backends and tests/test_kernels.
+scheduler sees the kernel's true arithmetic intensity, and a stable
+``name`` (``lut_cascade_resident``, ``lut_cascade_streamed``) that a
+device trace can find it by.  All three paths are validated bit-exact
+against the per-layer 'take' oracle over every paper task config by
+tests/test_backends and tests/test_kernels.
 """
 from __future__ import annotations
 
@@ -286,6 +288,7 @@ def _resident_call(codes_p: Array, amat_k: Array, tab_k: Array, *,
         cost_estimate=cascade_cost_estimate(layers, bb, mode="resident",
                                             block_b=block_b),
         interpret=interpret,
+        name="lut_cascade_resident",
     )(codes_p, amat_k, tab_k)
 
 
@@ -388,6 +391,7 @@ def _streamed_call(codes_p: Array, amat_k: Array, tab_k: Array, *,
             layers, bb, mode="streamed", block_b=block_b,
             unit_tile=unit_tile),
         interpret=interpret,
+        name="lut_cascade_streamed",
     )(jnp.asarray(cols), jnp.asarray(ends), jnp.asarray(outs),
       codes_p, amat_k, tab_k)
 

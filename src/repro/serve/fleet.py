@@ -51,11 +51,11 @@ from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro import backends
+from repro import backends, tracing
 from repro.serve.admission import (AdmissionController, AdmissionDecision,
                                    TenantSLO)
 from repro.serve.faults import DeviceLost, DrainTimeout, FaultInjector
-from repro.serve.lut_engine import (LATENCY_WINDOW, LUTEngine, LUTRequest)
+from repro.serve.lut_engine import LUTEngine, LUTRequest
 from repro.serve.registry import (ArtifactSource, ExecutorCache, Reference,
                                   SwapEvent, TenantRegistry)
 from repro.serve.supervision import (CircuitBreaker, DegradeEvent,
@@ -63,6 +63,10 @@ from repro.serve.supervision import (CircuitBreaker, DegradeEvent,
 from repro.stream.cell import (CompiledStreamCell, migrate_state_codes,
                                state_migration_mode)
 from repro.stream.session import StreamSession, StreamStore
+
+# per-request latency history kept for percentile stats; bounded so a
+# long-running serving process doesn't leak one float per request forever
+LATENCY_WINDOW = 10_000
 
 
 @dataclasses.dataclass
@@ -305,41 +309,44 @@ class LUTFleet:
         """Admit rows for one tenant.  Returns the accepted requests (in
         row order) and the admission decision; shed rows are simply not
         represented, deferred rows surface later through the same stats."""
-        lane = self._lane(model_id)
-        entry = self.registry.get(model_id)
-        self._sync_lane(lane)
-        xs = np.asarray(xs, np.float32)
-        if xs.ndim != 2:
-            raise ValueError(f"xs must be [n, in_features], got {xs.shape}")
-        b_state = lane.breaker.state(self._now())
-        if b_state == CircuitBreaker.OPEN or (
-                b_state == CircuitBreaker.HALF_OPEN
-                and lane.engine is not None and lane.engine.queue):
-            # quarantined: the lane is mid-incident — reject at the door
-            # through the tenant's shed/defer policy (DESIGN.md §11).
-            # HALF_OPEN with queued rows still quarantines (the probe uses
-            # the existing queue); an idle HALF_OPEN lane admits arrivals
-            # so something exists to probe with
-            decision = self.admission.quarantine(n=len(xs), slo=entry.slo)
-        else:
-            decision = self.admission.decide(
-                n=len(xs), queue_depth=lane.queue_depth(),
-                p99_us=self._p99_if_budgeted(lane, entry.slo), slo=entry.slo)
-        now = time.perf_counter()
-        if lane.t_first is None and (decision.accept or decision.defer):
-            lane.t_first = now
-        reqs: List[LUTRequest] = []
-        if decision.accept:
-            reqs = lane.engine.submit_many(xs[:decision.accept],
-                                           t_submit=now)
-        lane.stats.requests += decision.accept
-        lane.stats.shed += decision.shed
-        lane.stats.deferred += decision.defer
-        if decision.defer:
-            start = decision.accept
-            lane.deferred.extend(
-                (row, now) for row in xs[start:start + decision.defer])
-        return reqs, decision
+        with tracing.span("fleet.submit"):
+            lane = self._lane(model_id)
+            entry = self.registry.get(model_id)
+            self._sync_lane(lane)
+            xs = np.asarray(xs, np.float32)
+            if xs.ndim != 2:
+                raise ValueError(
+                    f"xs must be [n, in_features], got {xs.shape}")
+            b_state = lane.breaker.state(self._now())
+            if b_state == CircuitBreaker.OPEN or (
+                    b_state == CircuitBreaker.HALF_OPEN
+                    and lane.engine is not None and lane.engine.queue):
+                # quarantined: the lane is mid-incident — reject at the door
+                # through the tenant's shed/defer policy (DESIGN.md §11).
+                # HALF_OPEN with queued rows still quarantines (the probe uses
+                # the existing queue); an idle HALF_OPEN lane admits arrivals
+                # so something exists to probe with
+                decision = self.admission.quarantine(n=len(xs), slo=entry.slo)
+            else:
+                decision = self.admission.decide(
+                    n=len(xs), queue_depth=lane.queue_depth(),
+                    p99_us=self._p99_if_budgeted(lane, entry.slo),
+                    slo=entry.slo)
+            now = time.perf_counter()
+            if lane.t_first is None and (decision.accept or decision.defer):
+                lane.t_first = now
+            reqs: List[LUTRequest] = []
+            if decision.accept:
+                reqs = lane.engine.submit_many(xs[:decision.accept],
+                                               t_submit=now)
+            lane.stats.requests += decision.accept
+            lane.stats.shed += decision.shed
+            lane.stats.deferred += decision.defer
+            if decision.defer:
+                start = decision.accept
+                lane.deferred.extend(
+                    (row, now) for row in xs[start:start + decision.defer])
+            return reqs, decision
 
     def submit(self, model_id: str, x: np.ndarray
                ) -> Tuple[Optional[LUTRequest], AdmissionDecision]:
@@ -467,44 +474,46 @@ class LUTFleet:
         and recomputed.  ``timeout`` (seconds, injector clock) bounds the
         retire wait — a block older than that raises a diagnostic
         :class:`DrainTimeout` naming the lane."""
-        lanes = list(self._lanes.values())
-        if lanes:
-            # rotate the start so no tenant permanently dispatches first
-            self._rr = (self._rr + 1) % len(lanes)
-            lanes = lanes[self._rr:] + lanes[:self._rr]
-        for lane in lanes:
-            self._sync_lane(lane)
-            self._drain_deferred(lane)
-            self._admit_streams(lane)
-            fill = 1 if flush else min(self.min_fill, lane.block)
-            if len(lane.engine.queue) >= fill and self._may_dispatch(lane):
-                try:
-                    batch = lane.engine.dispatch_block()
-                except Exception as exc:
-                    # dispatch_block requeued the batch (exception-safe);
-                    # route the failure through retry/breaker/degrade
-                    self._on_lane_failure(lane, exc)
-                    continue
-                if self._faults is not None:
-                    # lane_dispatch seam: slow_start skews the clock AFTER
-                    # the block stamped its dispatch time, so its age
-                    # already exceeds the stall when supervision looks
-                    self._faults.lane_dispatch(scope=lane.model_id)
-                lane.stats.ticks += 1
-                lane.stats.rows_padded += lane.block - len(batch)
-                self._order.append((lane, lane.engine))
-        completed = 0
-        while len(self._order) > self.depth - 1:
-            completed += self._retire_one(timeout=timeout)
-        return completed
+        with tracing.span("fleet.tick"):
+            lanes = list(self._lanes.values())
+            if lanes:
+                # rotate the start so no tenant permanently dispatches first
+                self._rr = (self._rr + 1) % len(lanes)
+                lanes = lanes[self._rr:] + lanes[:self._rr]
+            for lane in lanes:
+                self._sync_lane(lane)
+                self._drain_deferred(lane)
+                self._admit_streams(lane)
+                fill = 1 if flush else min(self.min_fill, lane.block)
+                if len(lane.engine.queue) >= fill and self._may_dispatch(lane):
+                    try:
+                        batch = lane.engine.dispatch_block()
+                    except Exception as exc:
+                        # dispatch_block requeued the batch (exception-safe);
+                        # route the failure through retry/breaker/degrade
+                        self._on_lane_failure(lane, exc)
+                        continue
+                    if self._faults is not None:
+                        # lane_dispatch seam: slow_start skews the clock AFTER
+                        # the block stamped its dispatch time, so its age
+                        # already exceeds the stall when supervision looks
+                        self._faults.lane_dispatch(scope=lane.model_id)
+                    lane.stats.ticks += 1
+                    lane.stats.rows_padded += lane.block - len(batch)
+                    self._order.append((lane, lane.engine))
+            completed = 0
+            while len(self._order) > self.depth - 1:
+                completed += self._retire_one(timeout=timeout)
+            return completed
 
     def drain(self, timeout: Optional[float] = None) -> int:
         """Retire every in-flight block (the only unconditional wait).
         ``timeout`` bounds each wait as in :meth:`tick`."""
-        completed = 0
-        while self._order:
-            completed += self._retire_one(timeout=timeout)
-        return completed
+        with tracing.span("fleet.drain"):
+            completed = 0
+            while self._order:
+                completed += self._retire_one(timeout=timeout)
+            return completed
 
     def pump(self, max_ticks: int = 100_000,
              timeout: Optional[float] = None) -> int:

@@ -14,8 +14,11 @@ flight and only *retires* (waits on + scatters) a block once ``depth``
 blocks are outstanding.  Host-side work — padding the next block, fanning
 results back onto requests — overlaps device compute instead of
 serializing with it; nothing blocks until :meth:`drain`.  ``depth=1``
-reproduces the old synchronous tick exactly.  Per-tick wall latency lands
-in ``stats.tick_latencies_us`` (p50/p99 via ``stats.latency_us``).
+reproduces the old synchronous tick exactly.  With ``repro.tracing``
+enabled, each piece of a block's path is a span (``engine.fill``,
+``engine.put``, ``engine.launch``, ``engine.wait``, ``engine.fetch``,
+``engine.scatter``; a whole :meth:`LUTEngine.tick` is ``engine.tick``);
+off, the spans cost nothing.
 
 The cascade itself is a ``CompiledLUTNetwork.compile_backend`` executor —
 any registered lookup backend (take / onehot / pallas / fused, DESIGN.md
@@ -33,9 +36,11 @@ import dataclasses
 import time
 from typing import Deque, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.pipeline import CompiledLUTNetwork
 from repro.serve.faults import DrainTimeout
 
@@ -60,38 +65,20 @@ class LUTRequest:
     stream_id: Optional[object] = None
 
 
-# per-tick latency history kept for percentile stats; bounded so a
-# long-running serving process doesn't leak one float per tick forever
-LATENCY_WINDOW = 10_000
-
-
 @dataclasses.dataclass
 class LUTEngineStats:
     ticks: int = 0                      # blocks dispatched
     requests: int = 0
     rows_padded: int = 0
-    tick_latencies_us: "collections.deque[float]" = dataclasses.field(
-        default_factory=lambda: collections.deque(maxlen=LATENCY_WINDOW))
-
-    def latency_us(self, pct: float) -> float:
-        """Percentile (e.g. 50, 99) of per-tick wall latency over the last
-        ``LATENCY_WINDOW`` ticks, in us.  An empty window returns 0.0 —
-        callers (benchmark sweeps, admission control) must never have to
-        special-case an engine that has not ticked yet."""
-        if not self.tick_latencies_us:
-            return 0.0
-        return float(np.percentile(np.asarray(self.tick_latencies_us), pct))
 
     def summary(self) -> dict:
         """Flat JSON-ready snapshot — the supported way for benchmarks and
-        dashboards to consume stats (nobody should reach into the deque)."""
+        dashboards to consume stats.  Per-tick wall time is the
+        ``engine.tick`` span of ``repro.tracing``."""
         return {
             "ticks": self.ticks,
             "requests": self.requests,
             "rows_padded": self.rows_padded,
-            "p50_tick_us": round(self.latency_us(50), 1),
-            "p99_tick_us": round(self.latency_us(99), 1),
-            "latency_window": len(self.tick_latencies_us),
         }
 
 
@@ -238,22 +225,23 @@ class LUTEngine:
         second per-row pass by the caller.  In cell mode ``states``
         ([n, n_state] int codes, default initial) and ``stream_ids`` ride
         along the same way."""
-        xs = np.asarray(xs, np.float32)
-        base = self._next_rid
-        if self._cell is not None:
-            if states is None:
-                states = np.full((len(xs), self._n_state),
-                                 self._zero_state, np.int32)
+        with tracing.span("engine.enqueue"):
+            xs = np.asarray(xs, np.float32)
+            base = self._next_rid
+            if self._cell is not None:
+                if states is None:
+                    states = np.full((len(xs), self._n_state),
+                                     self._zero_state, np.int32)
+                else:
+                    states = np.asarray(states, np.int32)
+                reqs = [LUTRequest(rid=base + i, x=row, t_submit=t_submit,
+                                   state=s,
+                                   stream_id=(None if stream_ids is None
+                                              else stream_ids[i]))
+                        for i, (row, s) in enumerate(zip(xs, states))]
             else:
-                states = np.asarray(states, np.int32)
-            reqs = [LUTRequest(rid=base + i, x=row, t_submit=t_submit,
-                               state=s,
-                               stream_id=(None if stream_ids is None
-                                          else stream_ids[i]))
-                    for i, (row, s) in enumerate(zip(xs, states))]
-        else:
-            reqs = [LUTRequest(rid=base + i, x=row, t_submit=t_submit)
-                    for i, row in enumerate(xs)]
+                reqs = [LUTRequest(rid=base + i, x=row, t_submit=t_submit)
+                        for i, row in enumerate(xs)]
         self._next_rid += len(reqs)
         self.queue.extend(reqs)
         self.stats.requests += len(reqs)
@@ -276,15 +264,23 @@ class LUTEngine:
         exactly-one-step-queued invariant (the router/fleet busy sets)
         still holds, so the engine accepts new work after a poisoned
         batch."""
-        batch: List[LUTRequest] = []
-        while self.queue and len(batch) < self._block:
-            batch.append(self.queue.popleft())
-        if not batch:
-            return batch
-        xb = np.zeros((self._block, self._in_features), np.float32)
-        # one C-level fill, not a per-row python loop: the dispatch path is
-        # host-side work the async pipeline hides behind device compute
-        xb[:len(batch)] = [req.x for req in batch]
+        if not self.queue:
+            return []
+        with tracing.span("engine.fill"):
+            batch: List[LUTRequest] = []
+            while self.queue and len(batch) < self._block:
+                batch.append(self.queue.popleft())
+            if tracing.enabled():
+                # queue wait of the rows a caller stamped
+                now = time.perf_counter()
+                waits = [now - req.t_submit for req in batch if req.t_submit]
+                tracing.count("queue.wait_s", sum(waits))
+                tracing.count("queue.rows", len(waits))
+            xb = np.zeros((self._block, self._in_features), np.float32)
+            # one C-level fill, not a per-row python loop: the dispatch
+            # path is host-side work the async pipeline hides behind
+            # device compute
+            xb[:len(batch)] = [req.x for req in batch]
         # stamp BEFORE the fault seam: an injected hang skews the clock
         # during dispatch, so the block's age already exceeds the stall
         # when supervision first looks at it
@@ -294,14 +290,20 @@ class LUTEngine:
                 self._faults.executor_call(scope=self._scope,
                                            placement=self._fault_placement)
             if self._cell is not None:
-                sb = np.full((self._block, self._n_state), self._zero_state,
-                             np.int32)
-                sb[:len(batch)] = [req.state for req in batch]
-                codes, logits, s_next = self._cell.step(
-                    xb, sb, backend=self._cell_backend,
-                    placement=self._cell_placement)
+                # a stream step takes host arrays: the state fill and the
+                # transfer are part of its launch
+                with tracing.span("engine.launch"):
+                    sb = np.full((self._block, self._n_state),
+                                 self._zero_state, np.int32)
+                    sb[:len(batch)] = [req.state for req in batch]
+                    codes, logits, s_next = self._cell.step(
+                        xb, sb, backend=self._cell_backend,
+                        placement=self._cell_placement)
             else:
-                codes, logits = self._fwd(jnp.asarray(xb))
+                with tracing.span("engine.put"):
+                    xd = jnp.asarray(xb)
+                with tracing.span("engine.launch"):
+                    codes, logits = self._fwd(xd)
                 s_next = None
         except BaseException:
             for req in batch:
@@ -343,15 +345,22 @@ class LUTEngine:
         if not self._inflight:
             return []
         batch, codes, logits, s_next, _t0 = self._inflight.popleft()
-        codes_np, logits_np = np.asarray(codes), np.asarray(logits)
-        # list(ndarray) materializes the row views in one C loop
-        for req, c, lg in zip(batch, list(codes_np), list(logits_np)):
-            req.codes = c
-            req.logits = lg
-            req.done = True
-        if s_next is not None:
-            for req, s in zip(batch, list(np.asarray(s_next))):
-                req.next_state = s
+        if tracing.enabled():
+            # waiting on the device apart from the copies (untraced, the
+            # copies below wait)
+            with tracing.span("engine.wait"):
+                jax.block_until_ready((codes, logits, s_next))
+        with tracing.span("engine.fetch"):
+            codes_np, logits_np = np.asarray(codes), np.asarray(logits)
+        with tracing.span("engine.scatter"):
+            # list(ndarray) materializes the row views in one C loop
+            for req, c, lg in zip(batch, list(codes_np), list(logits_np)):
+                req.codes = c
+                req.logits = lg
+                req.done = True
+            if s_next is not None:
+                for req, s in zip(batch, list(np.asarray(s_next))):
+                    req.next_state = s
         return batch
 
     def _dispatch(self) -> int:
@@ -365,14 +374,12 @@ class LUTEngine:
         in flight.  Returns the number of requests completed this tick
         (with ``depth > 1`` completion trails dispatch — drain() retires
         the stragglers)."""
-        t0 = time.perf_counter()
-        dispatched = self._dispatch() if self.queue else 0
-        completed = 0
-        while len(self._inflight) > self._depth - 1:
-            completed += self._retire()
-        if dispatched or completed:
-            self.stats.tick_latencies_us.append(
-                (time.perf_counter() - t0) * 1e6)
+        with tracing.span("engine.tick"):
+            if self.queue:
+                self._dispatch()
+            completed = 0
+            while len(self._inflight) > self._depth - 1:
+                completed += self._retire()
         return completed
 
     def drain(self, timeout: Optional[float] = None) -> int:

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 import traffic
-from repro import pipeline
+from repro import pipeline, tracing
 from repro.configs import paper_tasks
 from repro.core import assemble
 from repro.serve import (AdmissionController, ExecutorCache, FaultInjector,
@@ -452,15 +452,29 @@ def test_fleet_p99_backpressure_sheds_new_arrivals(nets):
 # stats + engine hooks
 # ---------------------------------------------------------------------------
 
-def test_engine_stats_summary_and_empty_latency():
+def test_engine_stats_summary_and_empty_latency(nets):
     s = LUTEngineStats()
-    assert s.latency_us(50) == 0.0 == s.latency_us(99)   # empty window
-    d = s.summary()
-    assert d == {"ticks": 0, "requests": 0, "rows_padded": 0,
-                 "p50_tick_us": 0.0, "p99_tick_us": 0.0,
-                 "latency_window": 0}
-    s.tick_latencies_us.extend([10.0, 20.0])
-    assert s.summary()["p99_tick_us"] >= s.summary()["p50_tick_us"] > 0
+    assert s.summary() == {"ticks": 0, "requests": 0, "rows_padded": 0}
+    # per-tick wall time is the tracer's engine.tick span: nothing is
+    # recorded with tracing off, one span per tick with it on
+    eng = LUTEngine(nets["nid"], block=16, depth=2)
+    eng.submit_many(_rows(nets["nid"], 40, seed=3))
+    eng.tick()
+    assert tracing.snapshot()["spans"] == {}
+    tracing.reset()
+    tracing.enable()
+    try:
+        while eng.queue:
+            eng.tick()
+        eng.drain()
+        spans = tracing.snapshot()["spans"]
+    finally:
+        tracing.disable()
+        tracing.reset()
+    assert spans["engine.tick"][0] == 2
+    assert spans["engine.fetch"][0] == 3
+    assert eng.stats.summary() == {"ticks": 3, "requests": 40,
+                                   "rows_padded": 8}
 
 
 def test_fleet_stats_summary_empty():

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import pipeline
+from repro import pipeline, tracing
 from repro.configs import paper_tasks
 from repro.core import assemble
 from repro.data import synthetic
@@ -250,13 +250,24 @@ def test_lut_engine_async_double_buffered_matches_sync():
 
     sync = LUTEngine(compiled, block=32, depth=1)
     async_ = LUTEngine(compiled, block=32, depth=2)
-    np.testing.assert_allclose(async_.run(x), sync.run(x),
-                               rtol=1e-6, atol=1e-6)
+    want = sync.run(x)
+    # per-tick wall time is the tracer's engine.tick span
+    tracing.reset()
+    tracing.enable()
+    try:
+        got = async_.run(x)
+        spans = tracing.snapshot()["spans"]
+    finally:
+        tracing.disable()
+        tracing.reset()
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
     assert async_.stats.ticks == sync.stats.ticks == 4
     assert async_.stats.rows_padded == sync.stats.rows_padded == 28
     assert async_.inflight == 0          # drained
-    assert len(async_.stats.tick_latencies_us) >= 4
-    assert async_.stats.latency_us(99) >= async_.stats.latency_us(50) > 0
+    n_ticks, total_s, self_s = spans["engine.tick"]
+    assert n_ticks == 4 and total_s >= self_s > 0
+    # two blocks retire inside ticks, two in the drain
+    assert spans["engine.fetch"][0] == 4
 
 
 def test_lut_engine_async_completion_trails_dispatch():
