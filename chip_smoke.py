@@ -180,6 +180,7 @@ def serve_phase(nets, seed: int):
 
     from repro import backends
     from repro.kernels import ops
+    from repro.kernels.lut_cascade import address_passes
     from repro.pipeline import CompiledLUTNetwork
     from repro.serve.fleet import LUTFleet
 
@@ -228,7 +229,8 @@ def serve_phase(nets, seed: int):
         check(not bad and st.completed == st.requests,
               f"{task}: FleetStats {st.summary()}")
         entry = fleet.registry.get(task)
-        tuning = fused.device_tuning(entry.net._plans["fused"])
+        plan = entry.net._plans["fused"]
+        tuning = fused.device_tuning(plan)
         ex = fleet.registry.executor(task, backend="fused")
         hlo = ex._both.lower(jax.ShapeDtypeStruct(
             (BLOCK, entry.net.cfg.in_features), np.float32)).compile()
@@ -236,7 +238,8 @@ def serve_phase(nets, seed: int):
         print(f"  tenant {task}: impl={impl} "
               f"mode={tuning.mode} block_b={tuning.block_b} "
               f"unit_tile={tuning.unit_tile} "
-              f"device_kind={tuning.device_kind!r} "
+              f"device_kind={tuning.device_kind!r} address_passes="
+              f"{address_passes(plan.buffers['amat'], plan.meta['layers'])} "
               f"tpu_custom_call={'tpu_custom_call' in hlo.as_text()} "
               f"completed={st.completed} ticks={st.ticks}", flush=True)
         check("tpu_custom_call" in hlo.as_text(),

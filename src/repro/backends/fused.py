@@ -23,7 +23,7 @@ restored from old ``.npz`` artifacts are upgraded in place by
 (predictions stay bit-identical), the v2 metadata is rebuilt from the
 network config, and the tuning block defaults.
 
-Exactness constraint: addresses are formed in f32 on the MXU, so every
+Exactness constraint: addresses accumulate in f32 on the MXU, so every
 layer needs ``in_bits * fan_in <= 24`` (integers below 2^24 are exact in
 f32).  The paper's configs max out at 12; planning raises otherwise.
 """

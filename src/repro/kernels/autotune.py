@@ -26,9 +26,10 @@ that decision:
 The model is deliberately small: lookup tables admit no data reuse beyond
 what fits in VMEM, so the only real questions are "do the packed buffers
 fit?" (picks resident vs streamed) and "how big a batch tile keeps the
-kernel inside the VMEM budget?" (picks ``block_b``).  Both are answered
-against :data:`DEVICE_MODELS`, keyed by JAX's ``device_kind``, with the
-VMEM the compiled kernel allocates (``lut_cascade.vmem_bytes``).
+kernel inside the VMEM budget, and its select scan inside the tile size
+it runs fastest at?" (picks ``block_b``).  Both are answered against
+:data:`DEVICE_MODELS`, keyed by JAX's ``device_kind``, with the VMEM the
+compiled kernel allocates (``lut_cascade.vmem_bytes``).
 """
 from __future__ import annotations
 
@@ -50,8 +51,15 @@ DEVICE_MODELS: Dict[str, Dict[str, Any]] = {
         # ("Scoped allocation with size ... and limit 16.00M"); the chip
         # has more VMEM, but a kernel gets this much unless it asks
         "vmem_bytes": 16 * 2**20,
+        # the select scan's [block_b, 128] int32 address and carry tiles,
+        # at most: bulk rows/s at 64/128/256/512/1024-row tiles, mnist
+        # 1.63/1.93/1.66/1.42M, jsc_openml 11.3/12.4/8.9/7.2/6.1M
+        # (PERF.md).  Past 128 rows the scan slows, most likely as its
+        # tiles spill out of the vector registers.
+        "scan_bytes": 2 * 128 * 128 * 4,
         "source": "Google Cloud documentation, \"TPU v5e\" (peaks, HBM); "
-                  "vmem_bytes: Mosaic's scoped-VMEM limit on v5e",
+                  "vmem_bytes: Mosaic's scoped-VMEM limit on v5e; "
+                  "scan_bytes: measured on a v5e",
     },
     "cpu": {
         # the host streaming from LLC/DRAM; coarse on purpose: the model
@@ -59,6 +67,7 @@ DEVICE_MODELS: Dict[str, Dict[str, Any]] = {
         "peak_flops": 2e11,
         "hbm_bw": 4e10,
         "vmem_bytes": 8 * 2**20,
+        "scan_bytes": None,     # the host runs the XLA path, no scan
         "source": "rough host model, ranking only (no measurement)",
     },
 }
@@ -147,21 +156,21 @@ def roofline_candidates(layers: Sequence[Sequence[int]], *,
     the nightly choice-table artifact can serialize them verbatim.
     """
     # local import keeps this module importable without pulling kernels in
-    from repro.kernels.lut_cascade import (cascade_bytes, cascade_flops,
-                                           layers_v1, vmem_bytes)
+    from repro.kernels.lut_cascade import (LANE, cascade_bytes,
+                                           cascade_flops, vmem_bytes)
     dev = device_kind(device)
     m = DEVICE_MODELS[dev]
-    l4 = layers_v1(layers)
-    flops = cascade_flops(l4, batch)
+    flops = cascade_flops(layers, batch)
     rows: List[Dict[str, Any]] = []
     for mode in ("resident", "streamed"):
         for block_b in BLOCK_B_CANDIDATES:
             for unit_tile in (UNIT_TILE_CANDIDATES if mode == "streamed"
                               else (None,)):
                 tile = {} if unit_tile is None else {"unit_tile": unit_tile}
-                vmem = vmem_bytes(l4, mode=mode, block_b=block_b, **tile)
-                byts = cascade_bytes(l4, batch, mode=mode, block_b=block_b,
-                                     **tile)
+                vmem = vmem_bytes(layers, mode=mode, block_b=block_b,
+                                  **tile)
+                byts = cascade_bytes(layers, batch, mode=mode,
+                                     block_b=block_b, **tile)
                 t_comp = flops / m["peak_flops"]
                 t_mem = byts / m["hbm_bw"]
                 rows.append({
@@ -175,6 +184,8 @@ def roofline_candidates(layers: Sequence[Sequence[int]], *,
                     "rows_per_s": round(batch / max(t_comp, t_mem), 1),
                     "vmem_bytes": vmem,
                     "fits_vmem": vmem <= m["vmem_bytes"],
+                    "fits_scan": m["scan_bytes"] is None
+                    or 2 * block_b * LANE * 4 <= m["scan_bytes"],
                 })
     return rows
 
@@ -188,7 +199,9 @@ def _tuning(row: Dict[str, Any], **kw) -> KernelTuning:
 def pick_tuning(layers: Sequence[Sequence[int]], *, batch: int = 4096,
                 device: Optional[str] = None,
                 table_dtype: Optional[str] = None) -> KernelTuning:
-    """Model-driven choice: the fastest VMEM-feasible roofline candidate.
+    """Model-driven choice: the fastest VMEM-feasible roofline candidate
+    whose select-scan tiles fit the device's ``scan_bytes`` (any
+    VMEM-feasible one when none does).
 
     Ties break toward resident mode (no re-streaming) and larger batch
     tiles (fewer grid steps).  ``impl`` stays ``None`` (auto) so the same
@@ -198,6 +211,7 @@ def pick_tuning(layers: Sequence[Sequence[int]], *, batch: int = 4096,
     """
     rows = roofline_candidates(layers, batch=batch, device=device)
     fits = [r for r in rows if r["fits_vmem"]]
+    fits = [r for r in fits if r["fits_scan"]] or fits
     if not fits:
         return _tuning(min(rows, key=lambda r: r["vmem_bytes"]),
                        table_dtype=table_dtype, source="roofline")

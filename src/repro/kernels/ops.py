@@ -92,8 +92,8 @@ def lut_cascade(codes: Array, amat: Array, tables: Array, *,
       * ``impl=None`` (auto) runs the compiled Pallas kernel when
         :func:`pallas_interpret` is off (TPU), else the pure-jnp
         flat-gather path — interpret-mode Pallas is a debugging tool, not
-        a serving path.  The auto rule needs v2 layer metadata +
-        ``mappings``; legacy 4-tuple callers always get Pallas.
+        a serving path.  The auto rule needs ``mappings``; both paths
+        need v2 layer metadata.
 
     ``block_b`` overrides the tuned batch tile (benchmark sweeps)."""
     from repro.kernels.autotune import KernelTuning

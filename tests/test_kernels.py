@@ -4,6 +4,8 @@ oracles.  LUT lookup must be bit-exact; float kernels allclose.
 Property-based (hypothesis) variants live in test_properties.py, guarded by
 ``pytest.importorskip`` — hypothesis is a dev dependency.
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -195,21 +197,96 @@ def test_lut_cascade_xla_matches_oracle(task):
     np.testing.assert_array_equal(got, ref_out)
 
 
-@pytest.mark.parametrize("mode,unit_tile", [
-    ("resident", 8), ("streamed", 4), ("streamed", 8), ("streamed", 16),
-    ("streamed", 128), ("streamed", 256),
-])
-def test_lut_cascade_pallas_modes_match_oracle(mode, unit_tile):
-    """Resident and streamed Pallas tilings (interpret mode), ragged batch
-    (33 is off every block size, forcing the padded tail)."""
-    from repro.kernels.lut_cascade import lut_cascade_pallas
+def _drawn_fixture(kind, seed=0):
+    """(plan, layers, mappings, codes, take oracle's codes) for a small
+    network of drawn tables and mappings whose addresses need more than
+    one bf16 MXU pass:
 
-    plan, layers, mappings, codes, ref_out = _fused_fixture()
-    got = np.asarray(lut_cascade_pallas(
-        codes, jnp.asarray(plan.buffers["amat"]),
-        jnp.asarray(plan.buffers["tables"]), layers=layers,
-        block_b=16, mode=mode, unit_tile=unit_tile, interpret=True))
+    * ``dup_fan_in``: a 3-bit, 4-input mapping layer whose unit 0 reads
+      one input at fan-in positions 0 and 3, so its ``amat`` entry is
+      2^9 + 2^0 = 513, two bf16 pieces;
+    * ``wide_codes``: a 9-bit hidden layer, so the codes fed to the next
+      layer take two 8-bit slices."""
+    from repro import backends
+    from repro.pipeline import CompiledLUTNetwork, config_from_dict
+
+    in_features, input_bits, shapes = {       # (units, fan_in, bits, asm)
+        "dup_fan_in": (8, 3, [(8, 4, 2, False), (2, 4, 2, True)]),
+        "wide_codes": (8, 2, [(8, 2, 9, False), (4, 1, 2, False)]),
+    }[kind]
+    specs = [dict(zip(("units", "fan_in", "bits", "assemble"), s))
+             for s in shapes]
+    cfg = config_from_dict(dict(in_features=in_features,
+                                input_bits=input_bits, layers=specs))
+    rng = np.random.RandomState(seed)
+    tables, maps, prev, in_bits = [], [], in_features, input_bits
+    for spec in specs:
+        tables.append(rng.randint(0, 2 ** spec["bits"], size=(
+            spec["units"], 2 ** (in_bits * spec["fan_in"]))))
+        maps.append(None if spec["assemble"] else rng.randint(
+            0, prev, size=(spec["units"], spec["fan_in"])))
+        prev, in_bits = spec["units"], spec["bits"]
+    if kind == "dup_fan_in":
+        maps[0][0, 3] = maps[0][0, 0]
+    net = CompiledLUTNetwork(cfg, tables, maps, 0.0, 0.0, backend="fused")
+    plan = net.compile_backend("fused").plan
+    layers = tuple(tuple(int(v) for v in lm) for lm in plan.meta["layers"])
+    codes = jnp.asarray(rng.randint(0, 2 ** input_bits,
+                                    size=(33, in_features)), jnp.int32)
+    ref_out = np.asarray(
+        backends.get("take").run(net.compile_backend("take").plan, codes))
+    return plan, layers, None, codes, ref_out
+
+
+_PALLAS_TILINGS = [("resident", 8), ("streamed", 4), ("streamed", 8),
+                   ("streamed", 16), ("streamed", 128), ("streamed", 256)]
+
+
+@pytest.mark.parametrize("net,mode,unit_tile,passes", [
+    pytest.param("nid", mode, tile, 1, id=f"{mode}-{tile}")
+    for mode, tile in _PALLAS_TILINGS] + [
+    pytest.param(net, mode, tile, 2, id=f"{net}-{mode}-{tile}")
+    for net in ("dup_fan_in", "wide_codes")
+    for mode, tile in (("resident", 8), ("streamed", 8), ("streamed", 128))])
+def test_lut_cascade_pallas_modes_match_oracle(net, mode, unit_tile, passes):
+    """Resident and streamed Pallas tilings (interpret mode), ragged batch
+    (33 is off every block size, forcing the padded tail), bit-exact with
+    the address formed in as many one-pass bf16 matmuls as the plan needs:
+    one for a paper config, two for a repeated fan-in index or 9-bit
+    codes (the ``lut_cascade.address_passes`` counter reads it)."""
+    from repro import tracing
+    from repro.kernels.lut_cascade import address_passes, lut_cascade_pallas
+
+    plan, layers, mappings, codes, ref_out = (
+        _fused_fixture() if net == "nid" else _drawn_fixture(net))
+    assert address_passes(plan.buffers["amat"], layers) == passes
+    tracing.reset()
+    tracing.enable()
+    try:
+        got = np.asarray(lut_cascade_pallas(
+            codes, jnp.asarray(plan.buffers["amat"]),
+            jnp.asarray(plan.buffers["tables"]), layers=layers,
+            block_b=16, mode=mode, unit_tile=unit_tile, interpret=True))
+        counted = tracing.snapshot()["counters"]["lut_cascade.address_passes"]
+    finally:
+        tracing.disable()
+        tracing.reset()
     np.testing.assert_array_equal(got, ref_out)
+    assert counted == passes
+
+
+@pytest.mark.parametrize("name", ["jsc_openml", "mnist"])
+def test_bench_configs_take_one_address_pass(name):
+    """Both benchmark networks, as the benchmark draws them, form their
+    addresses in a single bf16 MXU pass."""
+    from bench import run as brun
+    from repro.kernels.lut_cascade import address_passes
+
+    cfg = brun.load_json(os.path.dirname(brun.__file__), "configs",
+                         name + ".json")
+    net, _, _ = brun.build_network(cfg)
+    plan = net.compile_backend("fused").plan
+    assert address_passes(plan.buffers["amat"], plan.meta["layers"]) == 1
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas", None])
