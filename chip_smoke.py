@@ -15,9 +15,10 @@ Phases (one chip):
            network equals the quantized forward on held-out rows
   persist  save/load round trip predicts identically
   compile  each tenant's fused executor compiled once (cold or cached)
-  serve    one LUTFleet, three tenants on the fused backend, strict
-           deploys, a few hundred ragged requests, every answer equal to
-           the take executor, no failure/retry/degrade in FleetStats
+  serve    one LUTFleet, the four Table-II designs as tenants on the
+           fused backend, strict deploys, a few hundred ragged requests,
+           every answer equal to the take executor, no
+           failure/retry/degrade in FleetStats
 """
 from __future__ import annotations
 
@@ -32,7 +33,7 @@ import traceback
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(HERE, "src"))
 
-TENANTS = ("nid", "jsc_openml", "mnist")
+TENANTS = ("nid", "jsc_cernbox", "jsc_openml", "mnist")
 BLOCK = 256
 
 
@@ -180,7 +181,7 @@ def serve_phase(nets, seed: int):
 
     from repro import backends
     from repro.kernels import ops
-    from repro.kernels.lut_cascade import address_passes
+    from repro.kernels.lut_cascade import address_passes, scan_steps
     from repro.pipeline import CompiledLUTNetwork
     from repro.serve.fleet import LUTFleet
 
@@ -235,11 +236,15 @@ def serve_phase(nets, seed: int):
         hlo = ex._both.lower(jax.ShapeDtypeStruct(
             (BLOCK, entry.net.cfg.in_features), np.float32)).compile()
         impl = tuning.impl or ("xla" if ops.pallas_interpret() else "pallas")
+        layers = plan.meta["layers"]
+        steps = scan_steps(layers, mode=tuning.mode,
+                           unit_tile=tuning.unit_tile)
         print(f"  tenant {task}: impl={impl} "
               f"mode={tuning.mode} block_b={tuning.block_b} "
               f"unit_tile={tuning.unit_tile} "
               f"device_kind={tuning.device_kind!r} address_passes="
-              f"{address_passes(plan.buffers['amat'], plan.meta['layers'])} "
+              f"{address_passes(plan.buffers['amat'], layers)} "
+              f"scan_steps={steps} "
               f"tpu_custom_call={'tpu_custom_call' in hlo.as_text()} "
               f"completed={st.completed} ticks={st.ticks}", flush=True)
         check("tpu_custom_call" in hlo.as_text(),
@@ -313,7 +318,9 @@ def main() -> int:
             trained = phase("train", train_phase, args.seed)
             trained = phase("persist", persist_phase, trained, args.seed)
             trained.backend = "fused"
-            nets = {"nid": init_net("nid", args.seed), "jsc_openml": trained,
+            nets = {"nid": init_net("nid", args.seed),
+                    "jsc_cernbox": init_net("jsc_cernbox", args.seed),
+                    "jsc_openml": trained,
                     "mnist": init_net("mnist", args.seed)}
             phase("compile", compile_phase, nets)
             phase("serve", serve_phase, nets, args.seed)

@@ -179,6 +179,21 @@ def address_passes(amat, layers: Sequence[Sequence[int]]) -> int:
     return code_slices(layers) * len(amat_pieces(amat))
 
 
+def scan_steps(layers: Sequence[Sequence[int]], *, mode: str = "resident",
+               unit_tile: int = LANE) -> int:
+    """Select-scan steps per batch tile: every lane chunk scans its
+    layer's table entries (resident), or the widest table's (streamed,
+    whose phases share one table tile shape).  One step is one
+    compare-and-select over a ``[block_b, chunk]`` tile."""
+    tile = LANE if mode == "resident" else unit_tile
+    blocks, total, _, _ = lane_layout(layers, tile)
+    chunk = min(LANE, tile)
+    if mode == "streamed":
+        return total // chunk * max(int(l[2]) for l in layers)
+    return sum(width // chunk * int(l[2])
+               for (_, _, width), l in zip(blocks, layers))
+
+
 def _scratch_dtype(n_h: int):
     """What the activation scratch holds: bf16 codes when one slice covers
     them (exact, and the matmul operand as stored), else int32 to slice."""
@@ -551,6 +566,8 @@ def lut_cascade_pallas(codes: Array, amat, tables, *,
     amat_k, tab_k = pack_lanes(amat, tables, layers, tile)
     tracing.count("lut_cascade.address_passes",
                   code_slices(layers) * len(amat_k))
+    tracing.count("lut_cascade.scan_steps",
+                  scan_steps(layers, mode=mode, unit_tile=unit_tile))
     _, _, w0p, _ = lane_layout(layers, tile)
     # zero rows and columns: valid addresses, multiplied by zero amat rows
     codes_p = jnp.pad(codes.astype(jnp.int32),

@@ -275,10 +275,11 @@ def test_lut_cascade_pallas_modes_match_oracle(net, mode, unit_tile, passes):
     assert counted == passes
 
 
-@pytest.mark.parametrize("name", ["jsc_openml", "mnist"])
+@pytest.mark.parametrize("name", ["jsc_openml", "mnist", "jsc_cernbox"])
 def test_bench_configs_take_one_address_pass(name):
-    """Both benchmark networks, as the benchmark draws them, form their
-    addresses in a single bf16 MXU pass."""
+    """Every benchmark network, as the benchmark draws it, forms its
+    addresses in a single bf16 MXU pass (jsc_cernbox's 8-bit input codes
+    fill the whole bf16-exact slice)."""
     from bench import run as brun
     from repro.kernels.lut_cascade import address_passes
 
@@ -287,6 +288,61 @@ def test_bench_configs_take_one_address_pass(name):
     net, _, _ = brun.build_network(cfg)
     plan = net.compile_backend("fused").plan
     assert address_passes(plan.buffers["amat"], plan.meta["layers"]) == 1
+
+
+def _table_ii_plan(task: str, seed: int = 0):
+    """A Table-II design's config and fused plan over drawn tables and
+    mappings."""
+    from repro.configs import paper_tasks
+    from repro.pipeline import CompiledLUTNetwork
+
+    cfg = paper_tasks.task_config(task)
+    rng = np.random.RandomState(seed)
+    tables, maps = [], []
+    for l, spec in enumerate(cfg.layers):
+        tables.append(rng.randint(0, 2 ** spec.bits, size=(
+            spec.units, 2 ** (cfg.in_bits(l) * spec.fan_in))))
+        maps.append(None if spec.assemble else rng.randint(
+            0, cfg.prev_width(l), size=(spec.units, spec.fan_in)))
+    net = CompiledLUTNetwork(cfg, tables, maps, 0.0, 0.0, backend="fused")
+    return cfg, net.compile_backend("fused").plan
+
+
+@pytest.mark.parametrize("task,steps", [
+    ("nid", 320), ("jsc_cernbox", 2560), ("jsc_openml", 640),
+    ("mnist", 2688)])
+def test_scan_steps_counter_per_table_ii_design(task, steps):
+    """``lut_cascade.scan_steps`` counts, once per kernel trace, the
+    select-scan steps of one batch tile at the tiling the v5e tuner
+    picks: lane chunks x table entries.  jsc_cernbox scans its 256-entry
+    tables over the same 10 lane chunks as jsc_openml's 64-entry ones,
+    4x the steps, and still forms its addresses in one pass."""
+    from repro import tracing
+    from repro.kernels import autotune
+    from repro.kernels.lut_cascade import lut_cascade_pallas, scan_steps
+
+    cfg, plan = _table_ii_plan(task)
+    layers = tuple(tuple(int(v) for v in lm) for lm in plan.meta["layers"])
+    t = autotune.pick_tuning(layers, device="TPU v5 lite")
+    assert scan_steps(layers, mode=t.mode, unit_tile=t.unit_tile) == steps
+
+    def kernel(codes):
+        return lut_cascade_pallas(
+            codes, plan.buffers["amat"], plan.buffers["tables"],
+            layers=layers, block_b=t.block_b, mode=t.mode,
+            unit_tile=t.unit_tile, interpret=True)
+
+    tracing.reset()
+    tracing.enable()
+    try:
+        jax.eval_shape(kernel, jax.ShapeDtypeStruct(
+            (t.block_b, cfg.in_features), jnp.int32))
+        counted = tracing.snapshot()["counters"]
+    finally:
+        tracing.disable()
+        tracing.reset()
+    assert counted["lut_cascade.scan_steps"] == steps
+    assert counted["lut_cascade.address_passes"] == 1
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas", None])
