@@ -181,7 +181,8 @@ def serve_phase(nets, seed: int):
 
     from repro import backends
     from repro.kernels import ops
-    from repro.kernels.lut_cascade import address_passes, scan_steps
+    from repro.kernels.lut_cascade import (address_passes, packed_chunks,
+                                           scan_steps, slot_bits)
     from repro.pipeline import CompiledLUTNetwork
     from repro.serve.fleet import LUTFleet
 
@@ -237,14 +238,16 @@ def serve_phase(nets, seed: int):
             (BLOCK, entry.net.cfg.in_features), np.float32)).compile()
         impl = tuning.impl or ("xla" if ops.pallas_interpret() else "pallas")
         layers = plan.meta["layers"]
-        steps = scan_steps(layers, mode=tuning.mode,
-                           unit_tile=tuning.unit_tile)
+        slots = slot_bits(plan.buffers["tables"], layers)
+        tiling = dict(mode=tuning.mode, unit_tile=tuning.unit_tile)
+        steps = scan_steps(layers, slots=slots, **tiling)
         print(f"  tenant {task}: impl={impl} "
               f"mode={tuning.mode} block_b={tuning.block_b} "
               f"unit_tile={tuning.unit_tile} "
               f"device_kind={tuning.device_kind!r} address_passes="
               f"{address_passes(plan.buffers['amat'], layers)} "
               f"scan_steps={steps} "
+              f"packed_chunks={packed_chunks(layers, slots, **tiling)} "
               f"tpu_custom_call={'tpu_custom_call' in hlo.as_text()} "
               f"completed={st.completed} ticks={st.ticks}", flush=True)
         check("tpu_custom_call" in hlo.as_text(),

@@ -45,9 +45,11 @@ Shared algebra (docs/KERNELS.md has the full walkthrough):
     (:func:`lane_layout`), so every lane offset the kernels touch is
     provably aligned, and the tables are transposed to ``[entries,
     units]`` so units sit on lanes.
-  * **Lookup** is a select scan over entry rows on the VPU (Pallas
-    modes) or a flat gather (XLA mode); padded rows/columns are zero
-    everywhere, so full-width padded matmuls stay exact.
+  * **Lookup** is a threshold scan over packed table words on the VPU
+    (Pallas modes: each int32 word holds ``32 / s`` codes of a layer's
+    ``s``-bit slot, :func:`slot_bits`) or a flat gather (XLA mode);
+    padded rows/columns are zero everywhere, so full-width padded
+    matmuls stay exact.
 
 Every ``pallas_call`` carries a :func:`cascade_cost_estimate` so XLA's
 scheduler sees the kernel's true arithmetic intensity, and a stable
@@ -179,19 +181,59 @@ def address_passes(amat, layers: Sequence[Sequence[int]]) -> int:
     return code_slices(layers) * len(amat_pieces(amat))
 
 
-def scan_steps(layers: Sequence[Sequence[int]], *, mode: str = "resident",
-               unit_tile: int = LANE) -> int:
-    """Select-scan steps per batch tile: every lane chunk scans its
-    layer's table entries (resident), or the widest table's (streamed,
-    whose phases share one table tile shape).  One step is one
-    compare-and-select over a ``[block_b, chunk]`` tile."""
+WORD_BITS = 32  # a packed table word: an int32 holding 32 // s slots
+
+
+def slot_bits(tables, layers: Sequence[Sequence[int]]) -> Tuple[int, ...]:
+    """Per layer, the bits ``s`` of one packed table slot: the bit length
+    of the layer's largest code rounded up to a power of two, so one
+    int32 word holds ``32 // s`` consecutive entries.  A layer holding a
+    negative code, or one wider than 16 bits, is not packed
+    (``s = 32``: one entry a word)."""
+    tables = np.asarray(tables)
+    out = []
+    for _, units, entries, off, *_ in layers:
+        t = tables[off:off + units, :entries]
+        need = max(1, int(t.max()).bit_length())
+        s = 1 << (need - 1).bit_length()
+        out.append(s if int(t.min()) >= 0 and s <= 16 else WORD_BITS)
+    return tuple(out)
+
+
+def table_words(entries: int, slot: int = WORD_BITS) -> int:
+    """Packed words of an ``entries``-entry table at ``slot`` bits a code."""
+    return -(-int(entries) // (WORD_BITS // slot))
+
+
+def _slots(layers, slots) -> Tuple[int, ...]:
+    return tuple(slots) if slots is not None else (WORD_BITS,) * len(layers)
+
+
+def _layer_chunks(layers: Sequence[Sequence[int]], mode: str,
+                  unit_tile: int) -> Tuple[int, ...]:
+    """Lane chunks per layer: the ``[block_b, chunk]`` tiles its lookup
+    runs on (``chunk = min(LANE, tile)``)."""
     tile = LANE if mode == "resident" else unit_tile
-    blocks, total, _, _ = lane_layout(layers, tile)
-    chunk = min(LANE, tile)
-    if mode == "streamed":
-        return total // chunk * max(int(l[2]) for l in layers)
-    return sum(width // chunk * int(l[2])
-               for (_, _, width), l in zip(blocks, layers))
+    blocks, _, _, _ = lane_layout(layers, tile)
+    return tuple(width // min(LANE, tile) for _, _, width in blocks)
+
+
+def scan_steps(layers: Sequence[Sequence[int]], *, mode: str = "resident",
+               unit_tile: int = LANE,
+               slots: Optional[Sequence[int]] = None) -> int:
+    """Lookup scan steps per batch tile: every lane chunk scans its
+    layer's packed table words (:func:`table_words` at the layer's
+    ``slots``; unpacked, one entry a word, when ``slots`` is None).  One
+    step is one compare-and-select over a ``[block_b, chunk]`` tile."""
+    return sum(n * table_words(l[2], s) for n, l, s in zip(
+        _layer_chunks(layers, mode, unit_tile), layers, _slots(layers, slots)))
+
+
+def packed_chunks(layers: Sequence[Sequence[int]], slots: Sequence[int], *,
+                  mode: str = "resident", unit_tile: int = LANE) -> int:
+    """Lane chunks whose lookup reads more than one code a word."""
+    return sum(n for n, s in zip(_layer_chunks(layers, mode, unit_tile),
+                                 slots) if s < WORD_BITS)
 
 
 def _scratch_dtype(n_h: int):
@@ -205,16 +247,31 @@ def _as_scratch(codes: Array, dtype) -> Array:
         codes.astype(jnp.float32).astype(dtype)
 
 
+def _pack_words(tab: np.ndarray, slot: int) -> np.ndarray:
+    """``tab [entries, units]`` as ``[words, units]`` int32: entry ``e`` in
+    word ``e // P``, bits ``(e % P) * slot`` up, ``P = 32 // slot``."""
+    if slot == WORD_BITS:
+        return tab.astype(np.int32)
+    per = WORD_BITS // slot
+    n_w = table_words(len(tab), slot)
+    t = np.zeros((n_w * per, tab.shape[1]), np.uint32)
+    t[:len(tab)] = tab
+    shifts = (np.arange(per, dtype=np.uint32) * slot)[None, :, None]
+    words = np.bitwise_or.reduce(t.reshape(n_w, per, -1) << shifts, axis=1)
+    return words.view(np.int32)
+
+
 def pack_lanes(amat, tables, layers: Sequence[Sequence[int]], tile: int):
     """Re-pack the plan's ``amat``/``tables`` into the lane layout.
 
     Host-side (numpy) and done once per trace: the results are constants
     of the jitted program.  Returns ``amat_k [n_a, a_dim, total]`` bf16
-    (the exact pieces of :func:`amat_pieces`) and the transposed tables
-    cut into lane chunks, ``tab_k [total // chunk, max_entries, chunk]``
-    int32 (``chunk = min(LANE, tile)``; entries on sublanes, units on
-    lanes, so a lookup reads one table row per entry at lane offset 0 —
-    Mosaic refuses a dynamic row load at any other)."""
+    (the exact pieces of :func:`amat_pieces`), the transposed tables
+    packed into words and cut into lane chunks, ``tab_k [total // chunk,
+    max_words, chunk]`` int32 (``chunk = min(LANE, tile)``; words on
+    sublanes, units on lanes, so a lookup reads one table row per word at
+    lane offset 0 — Mosaic refuses a dynamic row load at any other), and
+    the per-layer :func:`slot_bits` the words are packed at."""
     try:
         amat = np.asarray(amat, np.float32)
         tables = np.asarray(tables)
@@ -222,14 +279,18 @@ def pack_lanes(amat, tables, layers: Sequence[Sequence[int]], tile: int):
         raise TypeError("lut_cascade_pallas needs concrete amat/tables "
                         "(plan constants), not traced values") from e
     blocks, total, _, a_dim = lane_layout(layers, tile)
+    slots = slot_bits(tables, layers)
     amat_k = np.zeros((a_dim, total), np.float32)
-    tab_k = np.zeros((tables.shape[1], total), np.int32)
-    for (prev, units, _, off, *_), (_, col, _) in zip(layers, blocks):
+    tab_k = np.zeros((_max_words(layers, slots), total), np.int32)
+    for (prev, units, entries, off, *_), (_, col, _), slot in zip(
+            layers, blocks, slots):
         amat_k[:prev, col:col + units] = amat[:prev, off:off + units]
-        tab_k[:, col:col + units] = tables[off:off + units].T
+        words = _pack_words(tables[off:off + units, :entries].T, slot)
+        tab_k[:len(words), col:col + units] = words
     chunk = min(LANE, tile)
     tab_k = tab_k.reshape(len(tab_k), total // chunk, chunk)
-    return amat_pieces(amat_k), np.ascontiguousarray(tab_k.transpose(1, 0, 2))
+    return (amat_pieces(amat_k), np.ascontiguousarray(tab_k.transpose(1, 0, 2)),
+            slots)
 
 
 def _lookup(tab_ref, addr: Array, n_entries: int) -> Array:
@@ -245,6 +306,34 @@ def _lookup(tab_ref, addr: Array, n_entries: int) -> Array:
         return jnp.where(addr == e, row, acc)
     return jax.lax.fori_loop(0, n_entries, body,
                              jnp.zeros(addr.shape, jnp.int32))
+
+
+def _lookup_packed(tab_ref, addr: Array, n_words, log2_per, log2_slot
+                   ) -> Array:
+    """``out[b, u] = tab[addr[b, u], u]`` for one ``[words, chunk]`` tile
+    of packed words (:func:`_pack_words`), ``2**log2_per`` codes of
+    ``2**log2_slot`` bits a word.
+
+    A threshold scan over the words in ascending order keeps word ``w``
+    wherever ``addr >= w * P``, which leaves word ``addr // P`` in the
+    carry; one shift and one mask then pull the code out of its slot.
+    Only the address and the carry stay live, as in :func:`_lookup`.
+    The word count and both logs are Python ints, or int32 scalars read
+    from scalar prefetch (the streamed kernel's per-phase values)."""
+    def body(w, acc):
+        row = tab_ref[pl.ds(w, 1), :]                    # [1, chunk] int32
+        return jnp.where(addr >= (w << log2_per), row, acc)
+    acc = jax.lax.fori_loop(0, n_words, body,
+                            jnp.zeros(addr.shape, jnp.int32))
+    if isinstance(log2_per, int):
+        if log2_per == 0:
+            return acc
+        mask = (1 << (1 << log2_slot)) - 1
+    else:       # the slot may be the whole word: no shift past 31 bits
+        mask = jax.lax.shift_right_logical(
+            jnp.int32(-1), WORD_BITS - (1 << log2_slot))
+    shift = (addr & ((1 << log2_per) - 1)) << log2_slot
+    return jax.lax.shift_right_logical(acc, shift) & mask
 
 
 def _address(h: Array, amat_ref, cols, n_h: int) -> Array:
@@ -272,34 +361,47 @@ def _address(h: Array, amat_ref, cols, n_h: int) -> Array:
 # cost and VMEM model shared by both Pallas modes
 # ---------------------------------------------------------------------------
 
+def _max_words(layers, slots) -> int:
+    """Rows of a ``tab_k`` chunk: the most words any layer packs into."""
+    return max(table_words(l[2], s)
+               for l, s in zip(layers, _slots(layers, slots)))
+
+
 def cascade_flops(layers: Sequence[Sequence[int]], batch: int, *,
-                  n_a: Optional[int] = None) -> int:
+                  n_a: Optional[int] = None,
+                  slots: Optional[Sequence[int]] = None) -> int:
     """Work of one cascade pass: per layer, the address formation's
     one-pass bf16 matmuls (``n_h * n_a`` passes of 2*B*prev*units MXU
-    flops) plus the lookup's select scan (2*B*units*entries compare/select
-    VPU ops).  ``n_a`` is the plan's piece count, when known; otherwise
-    the most these layers can need (:func:`max_amat_pieces`)."""
+    flops) plus the lookup's scan (2*B*units*words compare/select VPU
+    ops) and, where words are packed, its unpacking (and, shift, shift,
+    mask: 4*B*units).  ``n_a`` is the plan's piece count, when known;
+    otherwise the most these layers can need (:func:`max_amat_pieces`).
+    ``slots`` are the plan's :func:`slot_bits`; unpacked when None (what
+    a model that sees shapes, not tables, can bound)."""
     passes = code_slices(layers) * (n_a or max_amat_pieces(layers))
     f = 0
-    for prev, units, entries, _, *_ in layers:
-        f += 2 * batch * prev * units * passes + 2 * batch * units * entries
+    for (prev, units, entries, _, *_), s in zip(layers, _slots(layers, slots)):
+        f += 2 * batch * prev * units * passes
+        f += 2 * batch * units * table_words(entries, s)
+        f += 4 * batch * units if s < WORD_BITS else 0
     return f
 
 
 def cascade_bytes(layers: Sequence[Sequence[int]], batch: int,
                   *, mode: str = "resident", block_b: int = 256,
-                  unit_tile: int = LANE, n_a: Optional[int] = None) -> int:
+                  unit_tile: int = LANE, n_a: Optional[int] = None,
+                  slots: Optional[Sequence[int]] = None) -> int:
     """HBM bytes of one cascade pass over the lane-packed constants: the
-    bf16 ``amat`` pieces, the int32 tables, the int32 codes in and out.
+    bf16 ``amat`` pieces, the int32 table words (unpacked when ``slots``
+    is None), the int32 codes in and out.
 
     Resident mode reads the packed buffers once; streamed mode re-streams
     the table/amat tiles for every batch tile (that re-read is the price
     of never holding the full table set in VMEM)."""
     tile = LANE if mode == "resident" else unit_tile
     blocks, total, w0p, a_dim = lane_layout(layers, tile)
-    max_entries = max(int(e) for _, _, e, _, *_ in layers)
     n_a = n_a or max_amat_pieces(layers)
-    const = (n_a * a_dim * 2 + max_entries * 4) * total
+    const = (n_a * a_dim * 2 + _max_words(layers, slots) * 4) * total
     io = batch * (w0p + blocks[-1][2]) * 4
     if mode == "streamed":
         n_bt = max(1, math.ceil(batch / block_b))
@@ -309,25 +411,28 @@ def cascade_bytes(layers: Sequence[Sequence[int]], batch: int,
 
 def cascade_cost_estimate(layers: Sequence[Sequence[int]], batch: int,
                           *, mode: str = "resident", block_b: int = 256,
-                          unit_tile: int = LANE,
-                          n_a: Optional[int] = None) -> pl.CostEstimate:
+                          unit_tile: int = LANE, n_a: Optional[int] = None,
+                          slots: Optional[Sequence[int]] = None
+                          ) -> pl.CostEstimate:
     """``pl.CostEstimate`` for one fused-cascade launch (both modes)."""
     return pl.CostEstimate(
-        flops=cascade_flops(layers, batch, n_a=n_a),
+        flops=cascade_flops(layers, batch, n_a=n_a, slots=slots),
         bytes_accessed=cascade_bytes(layers, batch, mode=mode,
                                      block_b=block_b, unit_tile=unit_tile,
-                                     n_a=n_a),
+                                     n_a=n_a, slots=slots),
         transcendentals=0)
 
 
 def vmem_bytes(layers: Sequence[Sequence[int]], *, mode: str,
-               block_b: int, unit_tile: int = LANE) -> int:
+               block_b: int, unit_tile: int = LANE,
+               slots: Optional[Sequence[int]] = None) -> int:
     """Upper bound on the VMEM the compiled kernel allocates, lane and
     sublane padding in.
 
     Counts every blocked operand double-buffered (constant index maps
     included): the int32 codes in and out, the bf16 ``amat`` pieces (as
-    many as :func:`max_amat_pieces` allows), the int32 tables; both
+    many as :func:`max_amat_pieces` allows), the int32 table words
+    (unpacked when ``slots`` is None, the bound the autotuner uses); both
     activation scratches (bf16 codes, or int32 where codes need more
     than one 8-bit slice), and the working values of one unit chunk —
     the loaded ``[block_b, a_dim]`` activation (with the int32 slice and
@@ -337,7 +442,7 @@ def vmem_bytes(layers: Sequence[Sequence[int]], *, mode: str,
     this counts."""
     tile = LANE if mode == "resident" else unit_tile
     blocks, total, w0p, a_dim = lane_layout(layers, tile)
-    ents = _round_up(max(int(e) for _, _, e, _, *_ in layers), SUBLANE)
+    ents = _round_up(_max_words(layers, slots), SUBLANE)
     n_a = max_amat_pieces(layers)
     sliced = code_slices(layers) > 1
     h_bytes = 4 if sliced else 2
@@ -357,16 +462,19 @@ def vmem_bytes(layers: Sequence[Sequence[int]], *, mode: str,
 # ---------------------------------------------------------------------------
 
 def _resident_kernel(codes_ref, amat_ref, tab_ref, out_ref, h0_ref, h1_ref,
-                     *, blocks, entries: Tuple[int, ...], chunk: int,
-                     n_h: int):
+                     *, blocks, packing: Tuple[Tuple[int, int, int], ...],
+                     chunk: int, n_h: int):
     """One batch tile through every layer; tables stay resident.
+
+    ``packing`` gives per layer its table's ``(words, log2 P, log2 s)``
+    (:func:`_lookup_packed`).
 
     Layer ``l`` reads its input from scratch ``h[(l-1) % 2]`` (the codes
     for layer 0) and writes its output codes chunk by chunk into
     ``h[l % 2]`` (the output block for the last layer)."""
     bufs = (h0_ref, h1_ref)
     last = len(blocks) - 1
-    for l, ((rows, col, width), n_e) in enumerate(zip(blocks, entries)):
+    for l, ((rows, col, width), pack) in enumerate(zip(blocks, packing)):
         if l == 0:
             h = _as_scratch(codes_ref[...], h0_ref.dtype)   # [BB, rows]
         else:
@@ -374,7 +482,8 @@ def _resident_kernel(codes_ref, amat_ref, tab_ref, out_ref, h0_ref, h1_ref,
         for c0 in range(0, width, chunk):
             cols = slice(col + c0, col + c0 + chunk)
             addr = _address(h, amat_ref, cols, n_h)
-            codes = _lookup(tab_ref.at[(col + c0) // chunk], addr, n_e)
+            codes = _lookup_packed(tab_ref.at[(col + c0) // chunk], addr,
+                                   *pack)
             if l == last:
                 out_ref[:, c0:c0 + chunk] = codes
             else:
@@ -382,11 +491,17 @@ def _resident_kernel(codes_ref, amat_ref, tab_ref, out_ref, h0_ref, h1_ref,
                     codes, h0_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("layers", "block_b",
+def _packing(layers, slots) -> Tuple[Tuple[int, int, int], ...]:
+    """Per layer ``(words, log2 P, log2 s)`` of its packed table."""
+    return tuple((table_words(l[2], s), (WORD_BITS // s).bit_length() - 1,
+                  s.bit_length() - 1) for l, s in zip(layers, slots))
+
+
+@functools.partial(jax.jit, static_argnames=("layers", "slots", "block_b",
                                              "interpret"))
 def _resident_call(codes_p: Array, amat_k: Array, tab_k: Array, *,
-                   layers: Tuple[LayerMeta, ...], block_b: int,
-                   interpret: bool) -> Array:
+                   layers: Tuple[LayerMeta, ...], slots: Tuple[int, ...],
+                   block_b: int, interpret: bool) -> Array:
     bb, w0p = codes_p.shape
     blocks, total, _, a_dim = lane_layout(layers, LANE)
     n_out_p = blocks[-1][2]
@@ -394,7 +509,7 @@ def _resident_call(codes_p: Array, amat_k: Array, tab_k: Array, *,
     h_dtype = _scratch_dtype(n_h)
     return pl.pallas_call(
         functools.partial(_resident_kernel, blocks=blocks,
-                          entries=tuple(e for _, _, e, *_ in layers),
+                          packing=_packing(layers, slots),
                           chunk=LANE, n_h=n_h),
         grid=(bb // block_b,),
         in_specs=[
@@ -408,7 +523,8 @@ def _resident_call(codes_p: Array, amat_k: Array, tab_k: Array, *,
                         pltpu.VMEM((block_b, a_dim), h_dtype)],
         cost_estimate=cascade_cost_estimate(layers, bb, mode="resident",
                                             block_b=block_b,
-                                            n_a=amat_k.shape[0]),
+                                            n_a=amat_k.shape[0],
+                                            slots=slots),
         interpret=interpret,
         name="lut_cascade_resident",
     )(codes_p, amat_k, tab_k)
@@ -418,31 +534,34 @@ def _resident_call(codes_p: Array, amat_k: Array, tab_k: Array, *,
 # mode "streamed": 2-D grid (batch-tile x phase), tiles streamed HBM->VMEM
 # ---------------------------------------------------------------------------
 
-def _phase_layout(layers: Tuple[LayerMeta, ...], unit_tile: int):
+def _phase_layout(layers: Tuple[LayerMeta, ...], unit_tile: int,
+                  slots: Tuple[int, ...]):
     """Static phase plan for the streamed kernel.
 
     A *phase* is one ``unit_tile``-wide column block of the lane layout,
     i.e. one (layer, unit-tile) pair; phases run sequentially on the inner
     grid axis, and phase ``j`` streams column block ``j`` of the packed
-    buffers.  Returns the per-phase scalar-prefetch arrays: the column
-    offset within its layer, and the layer-end and output flags."""
-    cols, ends, outs = [], [], []
+    buffers.  Returns the per-phase scalar-prefetch arrays, ``[6,
+    phases]`` int32: the column offset within its layer, the layer-end
+    and output flags, and its layer's table ``(words, log2 P, log2 s)``
+    (:func:`_packing`)."""
+    phases = []
     blocks, _, _, _ = lane_layout(layers, unit_tile)
     last = len(blocks) - 1
-    for li, (_, _, width) in enumerate(blocks):
+    for li, ((_, _, width), pack) in enumerate(
+            zip(blocks, _packing(layers, slots))):
         n_t = width // unit_tile
         for c in range(n_t):
-            cols.append(c * unit_tile)
-            ends.append(1 if c == n_t - 1 else 0)
-            outs.append(1 if li == last else 0)
-    return (np.asarray(cols, np.int32), np.asarray(ends, np.int32),
-            np.asarray(outs, np.int32))
+            phases.append((c * unit_tile, 1 if c == n_t - 1 else 0,
+                           1 if li == last else 0, *pack))
+    return np.asarray(phases, np.int32).T
 
 
 def _streamed_kernel(col_ref, end_ref, emit_ref,            # scalar prefetch
+                     words_ref, log2p_ref, log2s_ref,
                      codes_ref, amat_ref, tab_ref, out_ref,
                      h_ref, hn_ref, *, w0p: int, unit_tile: int,
-                     chunk: int, n_entries: int, n_h: int):
+                     chunk: int, n_h: int):
     """One (batch-tile, phase) grid step.
 
     ``h_ref`` holds the current layer's *input* codes (zero-padded to
@@ -450,7 +569,8 @@ def _streamed_kernel(col_ref, end_ref, emit_ref,            # scalar prefetch
     output tile by tile.  Both live in VMEM scratch and persist across the
     sequential phase axis.  ``amat_ref``/``tab_ref`` see only this phase's
     tile — the Pallas pipeline fetches phase j+1's tiles while phase j
-    computes."""
+    computes.  The lookup scans only this phase's layer's words, at its
+    layer's packing."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -464,9 +584,10 @@ def _streamed_kernel(col_ref, end_ref, emit_ref,            # scalar prefetch
     h = h_ref[...]
     col = col_ref[j]
     emit = emit_ref[j] == 1
+    pack = (words_ref[j], log2p_ref[j], log2s_ref[j])
     for c0 in range(0, unit_tile, chunk):
         addr = _address(h, amat_ref, slice(c0, c0 + chunk), n_h)
-        codes = _lookup(tab_ref.at[c0 // chunk], addr, n_entries)
+        codes = _lookup_packed(tab_ref.at[c0 // chunk], addr, *pack)
         at = pl.ds(pl.multiple_of(col + c0, chunk), chunk)
         hn_ref[:, at] = _as_scratch(codes, hn_ref.dtype)
 
@@ -479,28 +600,28 @@ def _streamed_kernel(col_ref, end_ref, emit_ref,            # scalar prefetch
         h_ref[...] = hn_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("layers", "block_b",
+@functools.partial(jax.jit, static_argnames=("layers", "slots", "block_b",
                                              "unit_tile", "interpret"))
 def _streamed_call(codes_p: Array, amat_k: Array, tab_k: Array, *,
-                   layers: Tuple[LayerMeta, ...], block_b: int,
-                   unit_tile: int, interpret: bool) -> Array:
+                   layers: Tuple[LayerMeta, ...], slots: Tuple[int, ...],
+                   block_b: int, unit_tile: int, interpret: bool) -> Array:
     bb, w0p = codes_p.shape
     blocks, _, _, a_dim = lane_layout(layers, unit_tile)
-    cols, ends, outs = _phase_layout(layers, unit_tile)
-    n_entries = tab_k.shape[1]
+    phases = _phase_layout(layers, unit_tile, slots)
+    n_words = tab_k.shape[1]
     chunk = min(LANE, unit_tile)
     n_out_p = blocks[-1][2]
     n_a = amat_k.shape[0]
     n_h = code_slices(layers)
     h_dtype = _scratch_dtype(n_h)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(bb // block_b, len(cols)),
+        num_scalar_prefetch=len(phases),
+        grid=(bb // block_b, phases.shape[1]),
         in_specs=[
             pl.BlockSpec((block_b, w0p), lambda i, j, *_: (i, 0)),
             pl.BlockSpec((n_a, a_dim, unit_tile),
                          lambda i, j, *_: (0, 0, j)),
-            pl.BlockSpec((unit_tile // chunk, n_entries, chunk),
+            pl.BlockSpec((unit_tile // chunk, n_words, chunk),
                          lambda i, j, *_: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, n_out_p), lambda i, j, *_: (i, 0)),
@@ -511,16 +632,15 @@ def _streamed_call(codes_p: Array, amat_k: Array, tab_k: Array, *,
     )
     return pl.pallas_call(
         functools.partial(_streamed_kernel, w0p=w0p, unit_tile=unit_tile,
-                          chunk=chunk, n_entries=n_entries, n_h=n_h),
+                          chunk=chunk, n_h=n_h),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bb, n_out_p), jnp.int32),
         cost_estimate=cascade_cost_estimate(
             layers, bb, mode="streamed", block_b=block_b,
-            unit_tile=unit_tile, n_a=n_a),
+            unit_tile=unit_tile, n_a=n_a, slots=slots),
         interpret=interpret,
         name="lut_cascade_streamed",
-    )(jnp.asarray(cols), jnp.asarray(ends), jnp.asarray(outs),
-      codes_p, amat_k, tab_k)
+    )(*map(jnp.asarray, phases), codes_p, amat_k, tab_k)
 
 
 # ---------------------------------------------------------------------------
@@ -563,22 +683,27 @@ def lut_cascade_pallas(codes: Array, amat, tables, *,
     # waste most of the kernel's work
     block_b = min(block_b, max(SUBLANE, 1 << (batch - 1).bit_length()))
     tile = LANE if mode == "resident" else unit_tile
-    amat_k, tab_k = pack_lanes(amat, tables, layers, tile)
+    amat_k, tab_k, slots = pack_lanes(amat, tables, layers, tile)
     tracing.count("lut_cascade.address_passes",
                   code_slices(layers) * len(amat_k))
     tracing.count("lut_cascade.scan_steps",
-                  scan_steps(layers, mode=mode, unit_tile=unit_tile))
+                  scan_steps(layers, mode=mode, unit_tile=unit_tile,
+                             slots=slots))
+    tracing.count("lut_cascade.packed_chunks",
+                  packed_chunks(layers, slots, mode=mode,
+                                unit_tile=unit_tile))
     _, _, w0p, _ = lane_layout(layers, tile)
     # zero rows and columns: valid addresses, multiplied by zero amat rows
     codes_p = jnp.pad(codes.astype(jnp.int32),
                       ((0, (-batch) % block_b), (0, w0p - codes.shape[1])))
     if mode == "streamed":
         out = _streamed_call(codes_p, amat_k, tab_k, layers=layers,
-                             block_b=block_b, unit_tile=unit_tile,
-                             interpret=interpret)
+                             slots=slots, block_b=block_b,
+                             unit_tile=unit_tile, interpret=interpret)
     else:
         out = _resident_call(codes_p, amat_k, tab_k, layers=layers,
-                             block_b=block_b, interpret=interpret)
+                             slots=slots, block_b=block_b,
+                             interpret=interpret)
     return out[:batch, :layers[-1][1]]
 
 

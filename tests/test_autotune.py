@@ -72,6 +72,20 @@ def test_pick_tuning_streams_when_tables_exceed_vmem():
     assert not any(r["fits_vmem"] for r in rows if r["mode"] == "resident")
 
 
+@pytest.mark.parametrize("task,mode", [
+    ("mnist", "streamed"), ("jsc_openml", "resident"),
+    ("jsc_cernbox", "resident")])
+def test_v5e_picks_of_the_bench_designs(task, mode):
+    """The v5e tiling of every benchmark design: the model sees shapes,
+    not tables, so packed table words leave it as it was, with the
+    select scan's tiles held to the measured ``scan_bytes`` (128 rows)
+    and one lane tile a streamed phase."""
+    layers = autotune.config_layers(paper_tasks.task_config(task))
+    for batch in (256, 4096, 8192, 65536):
+        t = autotune.pick_tuning(layers, batch=batch, device="TPU v5 lite")
+        assert (t.mode, t.block_b, t.unit_tile) == (mode, 128, 128)
+
+
 def test_device_kind_raises_on_unknown_kind():
     """The device table is keyed by JAX's device_kind; a device without a
     row gets an error, never another device's peaks or VMEM size."""

@@ -308,23 +308,17 @@ def _table_ii_plan(task: str, seed: int = 0):
     return cfg, net.compile_backend("fused").plan
 
 
-@pytest.mark.parametrize("task,steps", [
-    ("nid", 320), ("jsc_cernbox", 2560), ("jsc_openml", 640),
-    ("mnist", 2688)])
-def test_scan_steps_counter_per_table_ii_design(task, steps):
-    """``lut_cascade.scan_steps`` counts, once per kernel trace, the
-    select-scan steps of one batch tile at the tiling the v5e tuner
-    picks: lane chunks x table entries.  jsc_cernbox scans its 256-entry
-    tables over the same 10 lane chunks as jsc_openml's 64-entry ones,
-    4x the steps, and still forms its addresses in one pass."""
+def _table_ii_counters(task: str):
+    """The v5e tuner's tiling of a Table-II design over drawn tables, the
+    plan's layers and ``slot_bits``, and the counters one kernel trace
+    at that tiling reads."""
     from repro import tracing
     from repro.kernels import autotune
-    from repro.kernels.lut_cascade import lut_cascade_pallas, scan_steps
+    from repro.kernels.lut_cascade import lut_cascade_pallas, slot_bits
 
     cfg, plan = _table_ii_plan(task)
     layers = tuple(tuple(int(v) for v in lm) for lm in plan.meta["layers"])
     t = autotune.pick_tuning(layers, device="TPU v5 lite")
-    assert scan_steps(layers, mode=t.mode, unit_tile=t.unit_tile) == steps
 
     def kernel(codes):
         return lut_cascade_pallas(
@@ -341,8 +335,132 @@ def test_scan_steps_counter_per_table_ii_design(task, steps):
     finally:
         tracing.disable()
         tracing.reset()
+    return t, layers, slot_bits(plan.buffers["tables"], layers), counted
+
+
+@pytest.mark.parametrize("task,steps", [
+    ("nid", 20), ("jsc_cernbox", 352), ("jsc_openml", 88), ("mnist", 98)])
+def test_scan_steps_counter_per_table_ii_design(task, steps):
+    """``lut_cascade.scan_steps`` counts, once per kernel trace, the
+    lookup scan steps of one batch tile at the tiling the v5e tuner
+    picks: per lane chunk, its layer's packed table words.  nid's 2-bit
+    codes pack 16 to a word (4 words of 64 entries), the JSC designs'
+    3- and 4-bit codes 8 and their 8-bit logits 4 (jsc_openml 9 chunks
+    x 8 + 16, jsc_cernbox 9 x 32 + 64), mnist's 1-bit codes 32 and its
+    6-bit logits 4 (41 phases x 2 + 16): unpacked, 320 / 2,560 / 640 /
+    2,688.  Every design still forms its addresses in one pass."""
+    from repro.kernels.lut_cascade import scan_steps
+
+    t, layers, slots, counted = _table_ii_counters(task)
+    assert scan_steps(layers, mode=t.mode, unit_tile=t.unit_tile,
+                      slots=slots) == steps
     assert counted["lut_cascade.scan_steps"] == steps
     assert counted["lut_cascade.address_passes"] == 1
+
+
+@pytest.mark.parametrize("task,chunks,slots", [
+    ("nid", 5, (2,) * 5), ("jsc_cernbox", 10, (4,) * 6 + (8,)),
+    ("jsc_openml", 10, (4,) * 6 + (8,)), ("mnist", 42, (1,) * 5 + (8,))])
+def test_packed_chunks_counter_per_table_ii_design(task, chunks, slots):
+    """``lut_cascade.packed_chunks`` counts the lane chunks whose lookup
+    reads more than one code a word: every chunk of every Table-II
+    design, at the slot widths its drawn codes need."""
+    from repro.kernels.lut_cascade import packed_chunks
+
+    t, layers, got_slots, counted = _table_ii_counters(task)
+    assert got_slots == slots
+    assert packed_chunks(layers, got_slots, mode=t.mode,
+                         unit_tile=t.unit_tile) == chunks
+    assert counted["lut_cascade.packed_chunks"] == chunks
+
+
+def _packed_lookup(table, addr, *, dynamic: bool):
+    """``_lookup_packed`` over ``table [units, entries]`` (units <= 128)
+    in one interpret-mode ``pallas_call``: its packing static, or read
+    from scalar prefetch as the streamed kernel reads it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.lut_cascade import (LANE, _lookup_packed, _pack_words,
+                                           _packing, slot_bits)
+
+    units, entries = table.shape
+    layer = ((1, units, entries, 0, 1, 1, 0),)
+    slots = slot_bits(table, layer)
+    words = np.zeros((len(_pack_words(table.T, slots[0])), LANE), np.int32)
+    words[:, :units] = _pack_words(table.T, slots[0])
+    addr_p = np.zeros((len(addr), LANE), np.int32)
+    addr_p[:, :units] = addr
+    pack = _packing(layer, slots)[0]
+    out_shape = jax.ShapeDtypeStruct(addr_p.shape, jnp.int32)
+    if not dynamic:
+        def kernel(a_ref, t_ref, o_ref):
+            o_ref[...] = _lookup_packed(t_ref, a_ref[...], *pack)
+        out = pl.pallas_call(kernel, out_shape=out_shape,
+                             interpret=True)(addr_p, words)
+    else:
+        def kernel(p_ref, a_ref, t_ref, o_ref):
+            o_ref[...] = _lookup_packed(t_ref, a_ref[...], p_ref[0],
+                                        p_ref[1], p_ref[2])
+        whole = lambda shape: pl.BlockSpec(shape, lambda i, *_: (0, 0))
+        out = pl.pallas_call(kernel, out_shape=out_shape, interpret=True,
+                             grid_spec=pltpu.PrefetchScalarGridSpec(
+                                 num_scalar_prefetch=1, grid=(1,),
+                                 in_specs=[whole(addr_p.shape),
+                                           whole(words.shape)],
+                                 out_specs=whole(addr_p.shape)))(
+            jnp.asarray(pack, jnp.int32), addr_p, words)
+    return slots[0], np.asarray(out)[:, :units]
+
+
+@pytest.mark.parametrize("dynamic", [False, True], ids=["static", "prefetch"])
+@pytest.mark.parametrize("bits,entries", [
+    (1, 64), (1, 40), (1, 5), (2, 64), (2, 20), (3, 256), (4, 256),
+    (4, 6), (6, 64), (8, 256), (8, 3), (16, 4096), (16, 1)])
+def test_lookup_packed_matches_take(bits, entries, dynamic):
+    """The packed lookup equals ``tab[addr, u]`` at every slot width
+    (1/2/4/8/16 bits, and 3 and 6 rounded up), for tables whose entry
+    count is no multiple of the codes a word holds or is below it, with
+    the widest code in the top slot (its sign bit, where the code fills
+    its slot)."""
+    rng = np.random.RandomState(bits * 7919 + entries)
+    units, batch = 37, 40
+    table = rng.randint(0, 2 ** bits, size=(units, entries))
+    slot = 1 << (bits - 1).bit_length()
+    table[:, min(entries, 32 // slot) - 1] = 2 ** bits - 1
+    addr = rng.randint(0, entries, size=(batch, units))
+    addr[0] = min(entries, 32 // slot) - 1
+    got_slot, got = _packed_lookup(table, addr, dynamic=dynamic)
+    assert got_slot == slot
+    np.testing.assert_array_equal(
+        got, table[np.arange(units)[None, :], addr])
+
+
+@pytest.mark.parametrize("dynamic", [False, True], ids=["static", "prefetch"])
+@pytest.mark.parametrize("lo,hi", [(-128, 128), (0, 2 ** 17)],
+                         ids=["negative", "17bit"])
+def test_lookup_packed_unpacked_equals_lookup(lo, hi, dynamic):
+    """A table holding a negative code, or one wider than 16 bits, is not
+    packed (one entry a word), and its lookup equals the select scan of
+    ``_lookup`` that the per-layer kernel keeps."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.lut_cascade import WORD_BITS, _lookup
+
+    rng = np.random.RandomState(hi)
+    table = rng.randint(lo, hi, size=(128, 64))
+    table[0, 0] = hi - 1
+    addr = rng.randint(0, 64, size=(16, 128)).astype(np.int32)
+    slot, got = _packed_lookup(table, addr, dynamic=dynamic)
+    assert slot == WORD_BITS
+
+    def kernel(a_ref, t_ref, o_ref):
+        o_ref[...] = _lookup(t_ref, a_ref[...], 64)
+    want = pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(
+        addr.shape, jnp.int32), interpret=True)(
+        addr, np.ascontiguousarray(table.T.astype(np.int32)))
+    np.testing.assert_array_equal(got, np.asarray(want))
+    np.testing.assert_array_equal(got, table[np.arange(128)[None, :], addr])
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas", None])

@@ -47,18 +47,22 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _plan_buffers(layers):
-    """Zero ``amat``/``tables`` of the fused plan's shapes (a compile
-    needs shapes and constants, not trained values)."""
+def _plan_buffers(task):
+    """The fused plan's layers, a zero ``amat`` and tables holding each
+    layer's top code (a compile needs shapes and constants, not trained
+    values; the top code packs the table words as drawn tables do)."""
+    cfg = paper_tasks.task_config(task)
+    layers = tuple(autotune.config_layers(cfg))
     total = sum(u for _, u, *_ in layers)
     amat = np.zeros((max(p for p, *_ in layers), total), np.float32)
-    tables = np.zeros((total, max(e for _, _, e, *_ in layers)), np.int8)
-    return amat, tables
+    tables = np.zeros((total, max(e for _, _, e, *_ in layers)), np.int32)
+    for (_, units, _, off, *_), spec in zip(layers, cfg.layers):
+        tables[off:off + units] = 2 ** spec.bits - 1
+    return layers, amat, tables
 
 
 def _compile_cascade(task, one_chip, tuning):
-    layers = tuple(autotune.config_layers(paper_tasks.task_config(task)))
-    amat, tables = _plan_buffers(layers)
+    layers, amat, tables = _plan_buffers(task)
 
     def run(codes):
         return lut_cascade_pallas(codes, amat, tables, layers=layers,
@@ -83,17 +87,20 @@ def test_fused_cascade_compiles_at_picked_tuning(task, one_chip):
 @pytest.mark.parametrize("task", TABLE_II)
 def test_vmem_model_bounds_compiler_allocation(task, one_chip, monkeypatch):
     """The kernel compiles with its scoped-VMEM limit set to what
-    ``lut_cascade.vmem_bytes`` counts for the picked tuning: the model
-    the autotuner filters on is an upper bound, lane padding included."""
+    ``lut_cascade.vmem_bytes`` counts for the picked tuning at the
+    plan's packed table words: the model is an upper bound, lane padding
+    included, and the unpacked count the autotuner filters on bounds it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from repro.kernels.lut_cascade import vmem_bytes
+    from repro.kernels.lut_cascade import slot_bits, vmem_bytes
 
-    layers = autotune.config_layers(paper_tasks.task_config(task))
+    layers, _, tables = _plan_buffers(task)
     tuning = autotune.pick_tuning(layers, device=V5E)
-    limit = vmem_bytes(layers, mode=tuning.mode, block_b=tuning.block_b,
-                       unit_tile=tuning.unit_tile)
+    tiling = dict(mode=tuning.mode, block_b=tuning.block_b,
+                  unit_tile=tuning.unit_tile)
+    limit = vmem_bytes(layers, slots=slot_bits(tables, layers), **tiling)
+    assert limit <= vmem_bytes(layers, **tiling)
     assert limit <= autotune.DEVICE_MODELS[V5E]["vmem_bytes"]
     pallas_call = pl.pallas_call
 
